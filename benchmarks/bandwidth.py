@@ -49,15 +49,11 @@ def run() -> list[dict]:
         f = jax.jit(lambda s, r: ops.multiport_step(SPEC, cfg, s, r,
                                                     interpret=True))
         cost = f.lower(storage, reqs).compile().cost_analysis()
-        if isinstance(cost, list):        # pre-0.5 JAX returns [dict]
-            cost = cost[0]
         bytes_prop = float(cost.get("bytes accessed", 0.0))
 
         base = SinglePortNPass(SPEC)
         fb = jax.jit(lambda s, r: base.step(cfg, s, r))
         cost_b = fb.lower(storage, reqs).compile().cost_analysis()
-        if isinstance(cost_b, list):
-            cost_b = cost_b[0]
         bytes_base = float(cost_b.get("bytes accessed", 0.0))
 
         # wall time (CPU; interpret mode for the kernel — relative trend only)

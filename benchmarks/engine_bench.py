@@ -763,12 +763,7 @@ def main(argv=None) -> None:
         dyn = tr["dynamic"]["decode_traces"]
         sweep_traces = [x["bounded"]["decode_traces"]
                         for x in tl["per_cache_len"].values()]
-        if dyn < 0 or any(t < 0 for t in sweep_traces):
-            # -1 = this jax build exposes no jit-cache probe; that is an
-            # environment gap, not a retrace regression — don't fail on it
-            print("GATE SKIP: jit trace probe unavailable on this jax "
-                  "version; single-trace property not checked")
-        elif dyn != 1 or any(t != 1 for t in sweep_traces):
+        if dyn != 1 or any(t != 1 for t in sweep_traces):
             print(f"GATE FAIL: dynamic-grid decode path retraced "
                   f"(sweep: {dyn}, per-cache-len: {sweep_traces}; want 1)",
                   file=sys.stderr)

@@ -2,10 +2,12 @@
 artifacts (artifacts/dryrun/*.json — written by repro.launch.dryrun).
 
 Terms (seconds per step, PER CHIP; HLO numbers are already per-device):
-  compute    = dot_flops / 197e12            (v5e bf16 peak)
-  memory     = traffic_bytes / 819e9         (HBM bw)
-  collective = wire_bytes / 50e9             (one ICI link, conservative;
+  compute    = dot_flops / peak bf16 FLOP/s
+  memory     = traffic_bytes / peak HBM bytes/s
+  collective = wire_bytes / one ICI link's bytes/s (conservative;
                ring multipliers: all-reduce 2x, others 1x)
+with the peaks of the chip the records model, looked up in ``PEAKS`` by
+its ``device_kind`` (the dry-run models TPU v5e meshes).
 
 Also reports MODEL_FLOPS (6*N_active*D train, 2*N_active*D inference),
 the useful-compute ratio MODEL_FLOPS / (dot_flops * chips), the dominant
@@ -19,9 +21,23 @@ import os
 
 from repro.configs import registry
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+# Published per-chip peaks keyed by jax's ``device_kind``. TPU v5e: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600
+# Gbit/s of interconnect over 4 ICI links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak table row for a device kind; an unknown kind is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _RING_MULT = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
               "all-to-all": 1.0, "collective-permute": 1.0}
@@ -38,17 +54,18 @@ def model_flops(arch: str, kind: str, tokens: int) -> float:
 
 
 def analyze_record(rec: dict) -> dict:
+    pk = peaks(DRYRUN_DEVICE_KIND)
     h = rec["hlo"]
     chips = rec["chips"]
-    compute = h["dot_flops"] / PEAK_FLOPS
-    memory = h["traffic_bytes"] / HBM_BW
-    coll = wire_bytes(h["collective_bytes"]) / LINK_BW
+    compute = h["dot_flops"] / pk["flops"]
+    memory = h["traffic_bytes"] / pk["hbm_bw"]
+    coll = wire_bytes(h["collective_bytes"]) / pk["link_bw"]
     terms = {"compute": compute, "memory": memory, "collective": coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["kind"], rec["tokens_per_step"])
     useful = mf / max(h["dot_flops"] * chips, 1.0)
     step_time = max(terms.values())
-    mfu = (mf / chips / PEAK_FLOPS) / max(step_time, 1e-30)
+    mfu = (mf / chips / pk["flops"]) / max(step_time, 1e-30)
     hints = {
         "compute": "raise MFU: cut non-model dot flops (remat policy, "
                    "attention chunking) or use a faster layout",
@@ -89,7 +106,8 @@ def load_all(art_dir: str = "artifacts/dryrun", variant: str = "") -> list[dict]
 
 def main() -> None:
     rows = load_all()
-    print("# roofline terms per cell (seconds/step/chip; v5e constants)")
+    print(f"# roofline terms per cell (seconds/step/chip; "
+          f"{DRYRUN_DEVICE_KIND} peaks)")
     print("arch,shape,mesh,chips,compute_s,memory_s,collective_s,dominant,"
           "useful_ratio,roofline_mfu")
     for r in rows:

@@ -115,7 +115,7 @@ def step(spec: MemorySpec, config: PortConfig, storage: jax.Array,
 
 
 def step_banked(spec: MemorySpec, config: PortConfig, storage: jax.Array,
-                requests: Sequence[PortRequest], *, interpret: bool = True
+                requests: Sequence[PortRequest], *, interpret: bool | None = None
                 ) -> tuple[jax.Array, list[jax.Array]]:
     """Performance path: one physical traversal services all ports (Pallas)."""
     from repro.kernels import ops  # local import: kernels depend on core

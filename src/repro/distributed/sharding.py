@@ -37,25 +37,6 @@ from repro.configs.base import ArchConfig
 PyTree = Any
 
 
-def compat_shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (moved out of ``jax.experimental``
-    in newer JAX). ``check_rep=False`` everywhere: the mapped bodies launch
-    Pallas calls / psums whose replication the checker cannot see through.
-    """
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except ImportError:
-        from jax import shard_map as _sm          # >= 0.7 stable API
-        try:
-            return _sm(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-        except TypeError:                         # kwarg renamed over time
-            return _sm(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs)
-
-
 @dataclasses.dataclass(frozen=True)
 class Rules:
     """Axis assignment for one run."""

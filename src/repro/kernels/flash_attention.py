@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import resolve_interpret
+
 
 def _iota(n: int, dtype=jnp.int32) -> jax.Array:
     return jax.lax.broadcasted_iota(dtype, (n, 1), 0)[:, 0]
@@ -71,7 +73,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, q_tile: int = 128,
-                    k_tile: int = 128, interpret: bool = True) -> jax.Array:
+                    k_tile: int = 128,
+                    interpret: bool | None = None) -> jax.Array:
     """Tiled attention.
 
     Args:
@@ -109,6 +112,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((g, q_tile), jnp.float32),
             pltpu.VMEM((g, q_tile, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg, k, v)
     return out.reshape(b, h, sq, d)

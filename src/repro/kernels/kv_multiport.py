@@ -85,8 +85,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import (LANE, SUBLANE, clamp_seq_tile, iota,
                                   live_tile_bound, pack_words, pad_dim,
-                                  restore_live, slice_live, unpack_words,
-                                  word_pad)
+                                  resolve_interpret, restore_live,
+                                  slice_live, unpack_words, word_pad)
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
@@ -114,7 +114,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
 
     @pl.when(touched)
     def _service():
-        n_scr[0, 0] += 1                                  # serviced-tile count
+        n_scr[...] += 1                                   # serviced-tile count
         f32 = jnp.float32
         pos = tile_start + iota(seq_tile)                 # global positions [T]
 
@@ -174,7 +174,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
             res = jnp.concatenate(
                 [res, jnp.zeros((hp - h, dp), o_ref.dtype)], axis=0)
         o_ref[0] = res
-        t_ref[bb, 0] = n_scr[0, 0]
+        t_ref[0] = n_scr[...]
 
 
 def _split_kernel(len_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
@@ -216,7 +216,7 @@ def _split_kernel(len_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
 
     @pl.when(touched)
     def _service():
-        n_scr[0, 0] += 1                                  # serviced-tile count
+        n_scr[...] += 1                                   # serviced-tile count
         f32 = jnp.float32
         pos = tile_start + iota(seq_tile)                 # global positions [T]
 
@@ -288,7 +288,7 @@ def _split_kernel(len_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
                  jnp.zeros((hp, LANE - 2), jnp.float32)], axis=1))
         acc_ref[0] = jnp.concatenate(accs, axis=0)
         stats_ref[0] = jnp.concatenate(stats, axis=0)
-        t_ref[bb, 0] = n_scr[0, 0]
+        t_ref[0] = n_scr[...]
 
 
 def _combine_kernel(acc_ref, stats_ref, o_ref, *, num_kv_splits: int):
@@ -323,7 +323,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                         cache_len: jax.Array, *, seq_tile: int = 128,
                         live_len: int | None = None, length_mask: bool = True,
                         dynamic_grid: bool = False, num_kv_splits: int = 1,
-                        return_tiles: bool = False, interpret: bool = True
+                        return_tiles: bool = False, interpret: bool | None = None
                         ) -> tuple[jax.Array, ...]:
     """One decode step for a batch of sequences.
 
@@ -368,6 +368,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     h = q.shape[1]
     assert h % hkv == 0, "GQA requires H % Hkv == 0"
     g = h // hkv
+    interpret = resolve_interpret(interpret)
     if dynamic_grid and not length_mask:
         raise ValueError("dynamic_grid requires length_mask=True: rows "
                          "shorter than the batch max rely on the tile skip")
@@ -428,15 +429,15 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                 pl.BlockSpec(blocks["out_k"], per_tile),
                 pl.BlockSpec(blocks["out_v"], per_tile),
                 pl.BlockSpec(blocks["attn_out"], per_b),
-                # serviced-tile counts: [B, LANE] int32 so the accounting
-                # output is itself (8,128)-tileable (col 0 carries the count)
-                pl.BlockSpec(blocks["tiles"], lambda bb, t, L: (0, 0)),
+                # serviced-tile counts: one (8, 128) int32 block per row,
+                # the counter splatted over it (vector stores only)
+                pl.BlockSpec(blocks["tiles"], per_b),
             ],
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),        # m
                 pltpu.VMEM((h, 1), jnp.float32),        # l
                 pltpu.VMEM((h, dp), jnp.float32),       # acc
-                pltpu.VMEM((1, 1), jnp.int32),          # serviced tiles
+                pltpu.VMEM((SUBLANE, LANE), jnp.int32),  # serviced tiles
             ],
         )
         out_k, out_v, out, tiles = pl.pallas_call(
@@ -446,7 +447,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                 jax.ShapeDtypeStruct(ck_w.shape, ck_w.dtype),
                 jax.ShapeDtypeStruct(cv_w.shape, cv_w.dtype),
                 jax.ShapeDtypeStruct((b, hp, dp), q.dtype),
-                jax.ShapeDtypeStruct((b, LANE), jnp.int32),
+                jax.ShapeDtypeStruct((b, SUBLANE, LANE), jnp.int32),
             ],
             input_output_aliases={2: 0, 3: 1},          # caches in-place
             interpret=interpret,
@@ -475,13 +476,13 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                 pl.BlockSpec(blocks["out_v"], per_tile),
                 pl.BlockSpec(blocks["acc_partial"], per_b),
                 pl.BlockSpec(blocks["lse_partial"], per_b),
-                pl.BlockSpec(blocks["tiles"], lambda bb, t, L: (0, 0)),
+                pl.BlockSpec(blocks["tiles"], per_b),
             ],
             scratch_shapes=[
                 pltpu.VMEM((ns * h, 1), jnp.float32),   # m, per bank
                 pltpu.VMEM((ns * h, 1), jnp.float32),   # l, per bank
                 pltpu.VMEM((ns * h, dp), jnp.float32),  # acc, per bank
-                pltpu.VMEM((1, 1), jnp.int32),          # serviced tiles
+                pltpu.VMEM((SUBLANE, LANE), jnp.int32),  # serviced tiles
             ],
         )
         out_k, out_v, acc, stats, tiles = pl.pallas_call(
@@ -492,7 +493,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                 jax.ShapeDtypeStruct(cv_w.shape, cv_w.dtype),
                 jax.ShapeDtypeStruct((b, ns * hp, dp), jnp.float32),
                 jax.ShapeDtypeStruct((b, ns * hp, LANE), jnp.float32),
-                jax.ShapeDtypeStruct((b, LANE), jnp.int32),
+                jax.ShapeDtypeStruct((b, SUBLANE, LANE), jnp.int32),
             ],
             input_output_aliases={2: 0, 3: 1},          # caches in-place
             interpret=interpret,
@@ -513,7 +514,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     out_v = unpack_words(out_v, s, hkv, d)
     out = out[:, :h, :d]
     if return_tiles:
-        return out, out_k, out_v, tiles[:, 0]
+        return out, out_k, out_v, tiles[:, 0, 0]
     return out, out_k, out_v
 
 
@@ -536,7 +537,7 @@ def decode_block_specs(b: int, s: int, h: int, hkv: int, d: int,
         ("out_k", (1, tile, wp), (b, sp, wp)),
         ("out_v", (1, tile, wp), (b, sp, wp)),
         ("attn_out", (1, hp, dp), (b, hp, dp)),
-        ("tiles", (b, LANE), (b, LANE)),
+        ("tiles", (1, SUBLANE, LANE), (b, SUBLANE, LANE)),
     ]
 
 

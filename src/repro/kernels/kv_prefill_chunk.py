@@ -17,9 +17,11 @@ bounded traversal:
 
 Geometry is Mosaic-ready: the cache rides in WORD layout ``[B, Sp, hkv*Dp]``
 (tiles ``[seq_tile, word]``, minor dim lane-padded via ``word_pad``, per-head
-columns on lane boundaries), the q/out blocks are rank-4 ``[1, C, Hp, Dp]``
-(the old rank-5 ``[1, C, Hkv, G, D]`` blocks do not lower), and the
-per-sequence offset / chunk-length scalars ride in SMEM via scalar prefetch.
+columns on lane boundaries), the q/out blocks are head-major rows
+``[1, H * Cp, Dp]`` so every intermediate is a 2-D ``[G * Cp, T]`` or
+``[G * Cp, Dp]`` block per kv head (rank-3 ``[C, H, T]`` intermediates
+overflow VMEM at full width), and the per-sequence offset / chunk-length
+scalars ride in SMEM via scalar prefetch.
 
 Length bounding is the point: only tiles ``[0, ceil((offset+chunk_len) /
 seq_tile))`` are serviced — tiles wholly past a sequence's last query
@@ -46,20 +48,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import (LANE, SUBLANE, clamp_seq_tile, iota,
-                                  live_tile_bound, pack_words, pad_dim,
+from repro.kernels.tiling import (LANE, SUBLANE, VMEM_LIMIT_BYTES,
+                                  clamp_seq_tile, live_tile_bound,
+                                  pack_words, pad_dim, resolve_interpret,
                                   restore_live, slice_live, unpack_words,
                                   word_pad)
 
 
 def _kernel(off_ref, clen_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
             out_k_ref, out_v_ref, o_ref, t_ref, m_scr, l_scr, acc_scr,
-            n_scr, *, seq_tile: int, hkv: int, g: int, dp: int, chunk: int,
+            n_scr, *, seq_tile: int, hkv: int, g: int, dp: int, cp: int,
             scale: float):
     bb = pl.program_id(0)
     t = pl.program_id(1)
     n_tiles = pl.num_programs(1)          # static OR the dynamic live bound
-    h = hkv * g
+    rows = g * cp                         # query rows per kv head
 
     @pl.when(t == 0)
     def _init():
@@ -79,58 +82,62 @@ def _kernel(off_ref, clen_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
 
     @pl.when(touched)
     def _service():
-        n_scr[0, 0] += 1                                  # serviced-tile count
+        n_scr[...] += 1                                   # serviced-tile count
         f32 = jnp.float32
-        pos = tile_start + iota(seq_tile)                 # global [T]
-        rel = pos - off                                   # chunk row per slot
-        cp = new_k_ref.shape[1]                           # padded chunk rows
-        roww = iota(cp)
-
         # --- W port (priority A): land the chunk rows that map to this tile.
         # One-hot routing matrix [T, Cp] -> the whole-word scatter is one
-        # MXU matmul against the packed [Cp, word] chunk.
-        w_hit = (rel >= 0) & (rel < cl)                   # [T]
-        route = ((rel[:, None] == roww[None, :])
-                 & w_hit[:, None]).astype(f32)            # [T, Cp]
-        k_new = jax.lax.dot(route, new_k_ref[0].astype(f32),
+        # MXU matmul against the packed [Cp, word] chunk (exact: HIGHEST
+        # keeps every f32 bit of the routed word). Masks are built 2-D from
+        # iotas: Mosaic cannot reshape a 1-D bool vector into a column.
+        rel = (tile_start - off
+               + jax.lax.broadcasted_iota(jnp.int32, (seq_tile, cp), 0))
+        roww = jax.lax.broadcasted_iota(jnp.int32, (seq_tile, cp), 1)
+        route = ((rel == roww) & (roww < cl)).astype(f32)  # [T, Cp]
+        w_hit = (rel[:, :1] >= 0) & (rel[:, :1] < cl)      # [T, 1]
+        hi = jax.lax.Precision.HIGHEST
+        k_new = jax.lax.dot(route, new_k_ref[0].astype(f32), precision=hi,
                             preferred_element_type=f32)   # [T, word]
-        v_new = jax.lax.dot(route, new_v_ref[0].astype(f32),
+        v_new = jax.lax.dot(route, new_v_ref[0].astype(f32), precision=hi,
                             preferred_element_type=f32)
-        k_tile = jnp.where(w_hit[:, None], k_new.astype(k_ref.dtype), k_ref[0])
-        v_tile = jnp.where(w_hit[:, None], v_new.astype(v_ref.dtype), v_ref[0])
+        k_tile = jnp.where(w_hit, k_new.astype(k_ref.dtype), k_ref[0])
+        v_tile = jnp.where(w_hit, v_new.astype(v_ref.dtype), v_ref[0])
         out_k_ref[0] = k_tile                             # aliased write-thru
         out_v_ref[0] = v_tile
 
-        # --- R port (priority B): causal online-softmax over the live tile.
-        # per-kv-head scores on lane-aligned word columns (unrolled over the
-        # small static hkv)
-        q = q_ref[0].astype(f32)                          # [C, Hp, Dp]
-        s = jnp.concatenate(
-            [jax.lax.dot_general(
-                q[:, hk * g:(hk + 1) * g, :],
-                k_tile[:, hk * dp:(hk + 1) * dp].astype(f32),
-                (((2,), (1,)), ((), ())), preferred_element_type=f32)
-             for hk in range(hkv)], axis=1) * scale       # [C, H, T]
-        row = iota(chunk)
-        qpos = jnp.where(row < cl, off + row, off)        # [C]
-        valid = pos[None, :] <= qpos[:, None]             # [C, T]
-        vmask = valid[:, None, :]
-        s = jnp.where(vmask, s, -jnp.inf)
+        # --- R port (priority B): causal online-softmax over the live tile,
+        # one 2-D [G*Cp, T] score block per kv head (q rows are head-major:
+        # row = (head * Cp) + chunk row, see fused_chunk_append_attend)
+        row = jax.lax.broadcasted_iota(jnp.int32, (cp, seq_tile), 0)
+        pos = tile_start + jax.lax.broadcasted_iota(jnp.int32,
+                                                    (cp, seq_tile), 1)
+        qpos = jnp.where(row < cl, off + row, off)
+        valid = pos <= qpos                               # [Cp, T]
+        if g > 1:
+            valid = jnp.concatenate([valid] * g, axis=0)  # [G*Cp, T]
+        for hk in range(hkv):
+            r0 = hk * rows
+            q = q_ref[0, r0:r0 + rows, :].astype(f32)     # [G*Cp, Dp]
+            s = jax.lax.dot_general(
+                q, k_tile[:, hk * dp:(hk + 1) * dp].astype(f32),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * scale       # [G*Cp, T]
+            s = jnp.where(valid, s, -jnp.inf)
 
-        m_prev = m_scr[...]                               # [C, H]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_new), 0.0)
-        pr = jnp.exp(s - m_new[..., None])
-        pr = jnp.where(vmask, pr, 0.0)                    # [C, H, T]
-        l_scr[...] = l_scr[...] * alpha + pr.sum(axis=-1)
-        pv = jnp.concatenate(
-            [jax.lax.dot_general(
-                pr[:, hk * g:(hk + 1) * g, :],
-                v_tile[:, hk * dp:(hk + 1) * dp].astype(f32),
-                (((2,), (0,)), ((), ())), preferred_element_type=f32)
-             for hk in range(hkv)], axis=1)               # [C, H, Dp]
-        acc_scr[...] = acc_scr[...] * alpha[..., None] + pv
-        m_scr[...] = m_new
+            m_prev = m_scr[r0:r0 + rows, 0]               # [G*Cp]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1))
+            alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_new),
+                              0.0)
+            pr = jnp.exp(s - m_new[:, None])
+            pr = jnp.where(valid, pr, 0.0)                # [G*Cp, T]
+            l_scr[r0:r0 + rows, 0] = (l_scr[r0:r0 + rows, 0] * alpha
+                                      + pr.sum(axis=-1))
+            pv = jax.lax.dot_general(
+                pr, v_tile[:, hk * dp:(hk + 1) * dp].astype(f32),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)               # [G*Cp, Dp]
+            acc_scr[r0:r0 + rows, :] = (acc_scr[r0:r0 + rows, :]
+                                        * alpha[:, None] + pv)
+            m_scr[r0:r0 + rows, 0] = m_new
 
     @pl.when(jnp.logical_not(touched))
     def _pass_through():
@@ -142,14 +149,9 @@ def _kernel(off_ref, clen_ref, q_ref, k_ref, v_ref, new_k_ref, new_v_ref,
 
     @pl.when(t == n_tiles - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[..., None]
-        res = (acc_scr[...] / denom).astype(o_ref.dtype)  # [C, H, Dp]
-        hp = o_ref.shape[2]
-        if hp > h:                                        # head-pad rows
-            res = jnp.concatenate(
-                [res, jnp.zeros((chunk, hp - h, dp), o_ref.dtype)], axis=1)
-        o_ref[0] = res
-        t_ref[bb, 0] = n_scr[0, 0]
+        denom = jnp.maximum(l_scr[:, 0], 1e-30)[:, None]
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)  # [H*Cp, Dp]
+        t_ref[0] = n_scr[...]
 
 
 def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
@@ -159,7 +161,7 @@ def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
                               live_len: int | None = None,
                               dynamic_grid: bool = False,
                               return_tiles: bool = False,
-                              interpret: bool = True
+                              interpret: bool | None = None
                               ) -> tuple[jax.Array, ...]:
     """One chunked-prefill step for a batch of mid-prefill sequences.
 
@@ -176,8 +178,10 @@ def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
       chunk_len: [B] int32 — valid rows of each sequence's chunk.
       seq_tile:  tile size (capacities that are not tile multiples are
                  padded, keeping the tile aligned).
-      live_len:  static bound on the live prefix ``max(offset + chunk_len)``
-                 — only cache tiles below it are traversed; the suffix
+      live_len:  static bound on the live prefix
+                 ``max(offset + max(chunk_len, 1))`` (a row with no chunk
+                 rows still attends position ``offset``) — only cache
+                 tiles below it are traversed; the suffix
                  ``[live_len, S)`` is returned untouched. Ignored under
                  ``dynamic_grid``.
       dynamic_grid: bound the traversal grid with the RUNTIME live-tile
@@ -194,9 +198,9 @@ def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
     h = q.shape[2]
     assert h % hkv == 0, "GQA requires H % Hkv == 0"
     g = h // hkv
+    interpret = resolve_interpret(interpret)
 
     dp = word_pad(d)
-    hp = word_pad(h, SUBLANE)
     cp = word_pad(c, SUBLANE)
     wp = hkv * dp
     scale = 1.0 / (d ** 0.5)
@@ -223,43 +227,46 @@ def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
     else:
         n_tiles = grid_tiles
 
-    qp = pad_dim(pad_dim(q, 3, dp), 2, hp)                # [B, C, Hp, Dp]
+    # head-major query rows: [B, C, H, D] -> [B, H * Cp, Dp] with row
+    # h * Cp + c, so each kv head's G query heads form one contiguous
+    # [G * Cp, Dp] block and every intermediate in the kernel stays 2-D
+    qp = pad_dim(pad_dim(q, 3, dp), 1, cp).transpose(0, 2, 1, 3)
+    qp = qp.reshape(b, h * cp, dp)
     nk_w = pad_dim(pad_dim(new_k, 3, dp).reshape(b, c, wp), 1, cp)
     nv_w = pad_dim(pad_dim(new_v, 3, dp).reshape(b, c, wp), 1, cp)
 
     kernel = functools.partial(_kernel, seq_tile=seq_tile, hkv=hkv, g=g,
-                               dp=dp, chunk=c, scale=scale)
+                               dp=dp, cp=cp, scale=scale)
     # block SHAPES come from the same geometry table the Mosaic lint test
     # checks (chunk_block_specs) — the lint cannot drift from the launch
     blocks = {nm: blk
               for nm, blk, _ in chunk_block_specs(b, c, bound, h, hkv, d,
                                                   seq_tile)}
-    per_b3 = lambda bb, t, O, C: (bb, 0, 0)       # noqa: E731
-    per_b4 = lambda bb, t, O, C: (bb, 0, 0, 0)    # noqa: E731
+    per_b = lambda bb, t, O, C: (bb, 0, 0)        # noqa: E731
     per_tile = lambda bb, t, O, C: (bb, t, 0)     # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                            # offs, clens -> SMEM
         grid=(b, n_tiles),
         in_specs=[
-            pl.BlockSpec(blocks["q"], per_b4),
+            pl.BlockSpec(blocks["q"], per_b),
             pl.BlockSpec(blocks["cache_k"], per_tile),
             pl.BlockSpec(blocks["cache_v"], per_tile),
-            pl.BlockSpec(blocks["new_k"], per_b3),
-            pl.BlockSpec(blocks["new_v"], per_b3),
+            pl.BlockSpec(blocks["new_k"], per_b),
+            pl.BlockSpec(blocks["new_v"], per_b),
         ],
         out_specs=[
             pl.BlockSpec(blocks["out_k"], per_tile),
             pl.BlockSpec(blocks["out_v"], per_tile),
-            pl.BlockSpec(blocks["attn_out"], per_b4),
-            # serviced-tile counts: [B, LANE] int32 so the accounting output
-            # is itself (8,128)-tileable (col 0 carries the count)
-            pl.BlockSpec(blocks["tiles"], lambda bb, t, O, C: (0, 0)),
+            pl.BlockSpec(blocks["attn_out"], per_b),
+            # serviced-tile counts: one (8, 128) int32 block per row, the
+            # counter splatted over it (vector stores only; [:, 0, 0] reads)
+            pl.BlockSpec(blocks["tiles"], per_b),
         ],
         scratch_shapes=[
-            pltpu.VMEM((c, h), jnp.float32),              # m
-            pltpu.VMEM((c, h), jnp.float32),              # l
-            pltpu.VMEM((c, h, dp), jnp.float32),          # acc
-            pltpu.VMEM((1, 1), jnp.int32),                # serviced tiles
+            pltpu.VMEM((h * cp, 1), jnp.float32),         # m
+            pltpu.VMEM((h * cp, 1), jnp.float32),         # l
+            pltpu.VMEM((h * cp, dp), jnp.float32),        # acc
+            pltpu.VMEM((SUBLANE, LANE), jnp.int32),      # serviced tiles
         ],
     )
     out_k, out_v, out, tiles = pl.pallas_call(
@@ -268,42 +275,43 @@ def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
         out_shape=[
             jax.ShapeDtypeStruct(ck_w.shape, ck_w.dtype),
             jax.ShapeDtypeStruct(cv_w.shape, cv_w.dtype),
-            jax.ShapeDtypeStruct((b, c, hp, dp), q.dtype),
-            jax.ShapeDtypeStruct((b, LANE), jnp.int32),
+            jax.ShapeDtypeStruct((b, h * cp, dp), q.dtype),
+            jax.ShapeDtypeStruct((b, SUBLANE, LANE), jnp.int32),
         ],
         input_output_aliases={3: 0, 4: 1},                # caches in-place
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(offs, clens, qp, ck_w, cv_w, nk_w, nv_w)
 
     out_k, out_v = restore_live(full_k, full_v, out_k, out_v)
     out_k = unpack_words(out_k, s, hkv, d)
     out_v = unpack_words(out_v, s, hkv, d)
-    out = out[:, :, :h, :d]
+    out = out.reshape(b, h, cp, dp).transpose(0, 2, 1, 3)[:, :c, :, :d]
     if return_tiles:
-        return out, out_k, out_v, tiles[:, 0]
+        return out, out_k, out_v, tiles[:, 0, 0]
     return out, out_k, out_v
 
 
 def chunk_block_specs(b: int, c: int, s: int, h: int, hkv: int, d: int,
                       seq_tile: int) -> list[tuple[str, tuple, tuple]]:
     """The chunk kernel's block geometry as (name, block_shape, array_shape)
-    triples for the Mosaic geometry-lint test. Note every block is rank<=4:
-    the old rank-5 ``[1, C, Hkv, G, D]`` q/out blocks are flattened to
-    ``[1, C, Hp, Dp]``."""
+    triples for the Mosaic geometry-lint test. Every block is rank 3: the
+    q/out blocks are head-major ``[1, H * Cp, Dp]`` rows (Cp the chunk
+    padded to a sublane multiple), so the kernel's intermediates are 2-D."""
     dp = word_pad(d)
-    hp = word_pad(h, SUBLANE)
     cp = word_pad(c, SUBLANE)
     wp = hkv * dp
     sp = word_pad(s, seq_tile)
     tile = max(1, min(seq_tile, sp))
     return [
-        ("q", (1, c, hp, dp), (b, c, hp, dp)),
+        ("q", (1, h * cp, dp), (b, h * cp, dp)),
         ("cache_k", (1, tile, wp), (b, sp, wp)),
         ("cache_v", (1, tile, wp), (b, sp, wp)),
         ("new_k", (1, cp, wp), (b, cp, wp)),
         ("new_v", (1, cp, wp), (b, cp, wp)),
         ("out_k", (1, tile, wp), (b, sp, wp)),
         ("out_v", (1, tile, wp), (b, sp, wp)),
-        ("attn_out", (1, c, hp, dp), (b, c, hp, dp)),
-        ("tiles", (b, LANE), (b, LANE)),
+        ("attn_out", (1, h * cp, dp), (b, h * cp, dp)),
+        ("tiles", (1, SUBLANE, LANE), (b, SUBLANE, LANE)),
     ]

@@ -1,9 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Each wrapper owns the request preprocessing (address decomposition, write
-dedup) so the kernel bodies stay pure data movement + matmul, and exposes an
-``interpret`` flag: True (default) executes the kernel body in Python on CPU;
-on TPU deployments pass False to lower through Mosaic.
+Each wrapper owns the request preprocessing (write dedup, masking) so the
+kernel bodies stay pure data movement + matmul, and exposes an ``interpret``
+flag whose default (None) follows the backend — see
+``tiling.resolve_interpret``.
 """
 from __future__ import annotations
 
@@ -22,7 +22,8 @@ from repro.kernels import multiport_sram as mps
 
 
 def multiport_step(spec: MemorySpec, config: PortConfig, storage: jax.Array,
-                   requests: Sequence[PortRequest], *, interpret: bool = True
+                   requests: Sequence[PortRequest], *,
+                   interpret: bool | None = None
                    ) -> tuple[jax.Array, list[jax.Array]]:
     """Kernel-backed macro-cycle with the same contract as core.multiport.step.
 
@@ -36,30 +37,22 @@ def multiport_step(spec: MemorySpec, config: PortConfig, storage: jax.Array,
         if r.queue_len != q:
             raise ValueError("all port queues must share one queue length")
 
-    wpb = spec.words_per_bank
     order = config.service_order()                    # enabled, priority order
-    addrs, datas, masks = [], [], []
+    addrs, datas = [], []
     for p in order:
         r = requests[p]
         m = r.mask
         if config.roles[p] == WRITE:
             m = _dedup_last_wins(r.addr, m)          # last-wins in queue order
-        # clip OOB to an always-masked sentinel
-        in_range = (r.addr >= 0) & (r.addr < spec.num_words)
-        m = m & in_range
-        addrs.append(jnp.where(m, r.addr, 0))
+        # OOB and masked lanes carry the no-word address -1
+        m = m & (r.addr >= 0) & (r.addr < spec.num_words)
+        addrs.append(jnp.where(m, r.addr, -1).astype(jnp.int32))
         datas.append(r.data.astype(spec.dtype))
-        masks.append(m)
 
-    addr = jnp.stack(addrs)                           # [P_eff, Q]
-    data = jnp.stack(datas)                           # [P_eff, Q, W]
-    mask = jnp.stack(masks)                           # [P_eff, Q]
-    bank_id = addr // wpb
-    local = addr % wpb
-
-    banked = storage.reshape(spec.num_banks, wpb, spec.word_width)
+    banked = storage.reshape(spec.num_banks, spec.words_per_bank,
+                             spec.word_width)
     banked, packed = mps.multiport_sram_step(
-        banked, bank_id.astype(jnp.int32), local.astype(jnp.int32), data, mask,
+        banked, jnp.stack(addrs), jnp.stack(datas),
         roles=tuple(config.roles[p] for p in order), interpret=interpret)
     reads = [jnp.zeros((q, spec.word_width), spec.dtype)
              for _ in range(MAX_PORTS)]
@@ -81,8 +74,6 @@ def _kv_shard_wrap(kernel, mesh, mesh_axis: str, batch: int, n_in: int,
     if mesh is None:
         return kernel
     from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import compat_shard_map
     n = int(mesh.shape[mesh_axis])
     if n == 1:
         return kernel
@@ -91,9 +82,8 @@ def _kv_shard_wrap(kernel, mesh, mesh_axis: str, batch: int, n_in: int,
             f"kv-sharded kernel launch needs the batch ({batch}) to divide "
             f"across the {n}-way {mesh_axis!r} axis — pad the staged batch "
             f"to a whole number of rows per device")
-    return compat_shard_map(kernel, mesh,
-                            in_specs=(P(mesh_axis),) * n_in,
-                            out_specs=(P(mesh_axis),) * n_out)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(P(mesh_axis),) * n_in,
+                         out_specs=(P(mesh_axis),) * n_out, check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnames=("seq_tile", "live_len",
@@ -107,7 +97,7 @@ def fused_decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                            length_mask: bool = True,
                            dynamic_grid: bool = False,
                            num_kv_splits: int = 1,
-                           interpret: bool = True,
+                           interpret: bool | None = None,
                            mesh=None, mesh_axis: str = "kv",
                            port_mix: str = "wr"):
     """Scheduled-port-mix decode step. See kv_multiport.py.
@@ -156,7 +146,7 @@ def fused_prefill_chunk_attention(q: jax.Array, cache_k: jax.Array,
                                   seq_tile: int = 128,
                                   live_len: int | None = None,
                                   dynamic_grid: bool = False,
-                                  interpret: bool = True,
+                                  interpret: bool | None = None,
                                   mesh=None, mesh_axis: str = "kv",
                                   port_mix: str = "wr"):
     """Scheduled-port-mix chunked-prefill step.
@@ -186,6 +176,6 @@ def fused_prefill_chunk_attention(q: jax.Array, cache_k: jax.Array,
 @functools.partial(jax.jit, static_argnames=("causal", "q_tile", "k_tile", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, q_tile: int = 128, k_tile: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     return fa.flash_attention(q, k, v, causal=causal, q_tile=q_tile,
                               k_tile=k_tile, interpret=interpret)

@@ -32,7 +32,24 @@ import jax.numpy as jnp
 LANE = 128
 SUBLANE = 8
 
+# Scoped VMEM a kernel may claim: half of a TPU v5e core's 128 MiB. The
+# compiler's default scope (16 MiB) refuses the chunked-prefill kernel at
+# 256-token chunks of tinyllama-1.1b; the other half stays free for the
+# compiler's own buffers.
+VMEM_LIMIT_BYTES = 64 << 20
+
 _fit_warned: set = set()
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one place the Pallas execution mode is chosen. ``None`` — the
+    default of every ``interpret`` parameter in this package — follows the
+    backend arrays live on: kernel bodies run in the Pallas interpreter on
+    CPU and compile through Mosaic on a TPU. An explicit bool wins (a test
+    compiling for a described, unattached chip passes False)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def word_pad(n: int, unit: int = LANE) -> int:

@@ -1,58 +1,11 @@
-"""Production mesh builders + JAX-version compat shims.
+"""Production mesh builders.
 
 Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
-
-Compat: newer JAX exposes ``jax.sharding.AxisType`` (and ``jax.set_mesh``)
-for the sharding-in-types world; the pinned 0.4.x line has neither. All mesh
-construction in this repo goes through :func:`make_mesh` / :func:`use_mesh`
-below, which feature-detect and degrade gracefully:
-
-  * ``make_mesh(shape, axes)`` — ``jax.make_mesh`` with ``axis_types`` only
-    when the running JAX supports it.
-  * ``use_mesh(mesh)``        — ``jax.set_mesh`` when present, else the
-    classic ``Mesh`` context manager (a no-op wrapper for jit calls that
-    pass explicit ``NamedSharding``s, which is how this repo shards).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-
-def make_mesh(shape, axes, *, devices=None):
-    """Version-portable ``jax.make_mesh``.
-
-    Uses ``AxisType.Auto`` axis types when the running JAX exposes them
-    (>= 0.5-era sharding-in-types API); otherwise builds a plain ``Mesh``.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes),
-                                 **kwargs)
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def use_mesh(mesh):
-    """Version-portable ``with jax.set_mesh(mesh)``.
-
-    Explicit-sharding jits (``in_shardings=NamedSharding(...)``) don't need an
-    ambient mesh, so on older JAX the classic ``Mesh`` context manager (or
-    nothing at all) is sufficient.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
 
 
 def make_kv_mesh(n_shards: int, *, axis: str = "kv", devices=None):
@@ -84,9 +37,5 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_debug_mesh(shape=(2, 4), axes=("data", "model")):
-    """Small mesh for multi-device CPU tests (8 forced host devices)."""
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

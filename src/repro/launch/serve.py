@@ -1,5 +1,9 @@
 """Serving launcher: the multi-port engine over a token-model architecture.
 
+Serves the architecture's published configuration; ``--reduced`` swaps in
+its small preset, the size CPU runs use (Pallas kernels interpreted there,
+compiled on a TPU):
+
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --reduced --requests 8 --max-new 8 [--single-port]
 
@@ -7,19 +11,21 @@ Multi-device (data-parallel KV — the paged pool sharded page-aligned over a
 ``kv`` mesh axis, kernels shard_map'd by home device):
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        PYTHONPATH=src python -m repro.launch.serve --kv-shards 4
+        PYTHONPATH=src python -m repro.launch.serve --reduced --kv-shards 4
 
 Open-loop (requests ARRIVE on a virtual-clock schedule instead of all being
 submitted up front — seeded Poisson via ``--arrival-rate``, or a JSONL
 trace via ``--trace``; ``--slo`` prints p99-TTFT SLO attainment in
 virtual-clock ticks, 1 tick = 1 pool traversal):
 
-    PYTHONPATH=src python -m repro.launch.serve --arrival-rate 0.25 \
-        --requests 16 --slo 120
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
+        --arrival-rate 0.25 --requests 16 --slo 120
 """
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import time
 
 import jax
@@ -32,10 +38,27 @@ from repro.serve import traffic
 from repro.serve.engine import MultiPortEngine
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX reads
+    the variable itself, so nothing else is set), else a fixed
+    ``.jax_cache/`` at the checkout root — a fixed path, because the path
+    is part of what a later run must find again. Call it from an entry
+    point, never at import."""
+    got = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if got:
+        return got
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=registry.ARCH_IDS)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the architecture's small preset instead of "
+                         "its published widths (the size for CPU runs)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4,
@@ -83,8 +106,6 @@ def main() -> None:
                     help="per-traversal port budget (1-4, the paper's B1B0 "
                          "knob); 1 degrades the attention compute to the "
                          "two-pass W-then-R oracle")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="lower Pallas kernels through Mosaic (TPU)")
     ap.add_argument("--arrival-rate", type=float, default=None,
                     help="open-loop mode: seeded Poisson arrivals at this "
                          "many requests per virtual tick (1 tick = 1 pool "
@@ -126,6 +147,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.trace and args.arrival_rate is not None:
         raise SystemExit("--trace and --arrival-rate are exclusive")
+    enable_compile_cache()
 
     cfg = registry.get(args.arch, reduced=args.reduced)
     if cfg.input_mode != "tokens":
@@ -162,7 +184,7 @@ def main() -> None:
             raise SystemExit(f"--kv-shards: {e}")
         print(f"data-parallel KV: pool sharded page-aligned over "
               f"{args.kv_shards} devices ({[str(d) for d in mesh.devices.flat]})")
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = init_params(jax.random.PRNGKey(args.seed), cfg)
     eng = MultiPortEngine(params, cfg, slots=args.slots,
                           max_slots=max(args.max_slots, args.slots),
                           max_len=args.max_len,
@@ -173,7 +195,6 @@ def main() -> None:
                           length_bound=not args.no_length_bound,
                           dynamic_grid=not args.no_dynamic_grid,
                           num_kv_splits=args.num_kv_splits,
-                          interpret=not args.no_interpret,
                           mesh=mesh,
                           schedule_mode=args.schedule_mode,
                           max_ports=args.max_ports,

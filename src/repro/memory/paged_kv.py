@@ -30,10 +30,10 @@ vectorized request queue, so e.g. every active slot's decode append is one
 port-A transaction.
 
 ``use_kernel=True`` backs the data plane with ``core.step_banked`` (the
-Pallas one-traversal kernel; ``interpret=`` executes it in Python on CPU
-CI), ``use_kernel=False`` keeps the jnp oracle ``core.step``. The page-table
-bookkeeping stays host-side (python ints — it is control plane, like the
-engine's scheduler).
+Pallas one-traversal kernel, interpreted on CPU and compiled on a TPU —
+see ``kernels.tiling.resolve_interpret``), ``use_kernel=False`` keeps the
+jnp oracle ``core.step``. The page-table bookkeeping stays host-side
+(python ints — it is control plane, like the engine's scheduler).
 
 **Multi-device sharding** (``kv_shards`` > 1, optionally backed by a real
 ``mesh`` with a ``kv`` axis): the pool's word axis — its sequence/page axis
@@ -92,9 +92,9 @@ import numpy as np
 
 from repro.core import (MemorySpec, PortConfig, READ, WRITE, PortRequest,
                         empty_request, step, step_banked)
-from repro.distributed.sharding import (KVShardPlan, compat_shard_map,
-                                        kv_pool_spec, kv_shard_plan,
-                                        shard_of_pages)
+from repro.distributed.sharding import (KVShardPlan, kv_pool_spec,
+                                        kv_shard_plan, shard_of_pages)
+from repro.kernels.multiport_sram import bank_count
 from repro.kernels.tiling import word_pad
 
 # pool port indices
@@ -186,7 +186,7 @@ def seq_tile_buckets(max_len: int, seq_tile: int) -> tuple[int, ...]:
 @functools.partial(jax.jit, static_argnames=("spec", "config", "use_kernel",
                                              "interpret"))
 def _pool_step(spec, config, storage, requests, *, use_kernel: bool,
-               interpret: bool):
+               interpret: Optional[bool]):
     if use_kernel:
         return step_banked(spec, config, storage, requests,
                            interpret=interpret)
@@ -195,7 +195,7 @@ def _pool_step(spec, config, storage, requests, *, use_kernel: bool,
 
 @functools.lru_cache(maxsize=None)
 def _sharded_pool_step(local_spec, config, mesh, kv_axis: str, wps: int,
-                       use_kernel: bool, interpret: bool):
+                       use_kernel: bool, interpret: Optional[bool]):
     """Jitted shard-mapped pool step: each shard services the request lanes
     whose global addresses land in its ``wps``-word range (local
     re-addressing; lanes owned by other shards are masked off — masked
@@ -220,10 +220,9 @@ def _sharded_pool_step(local_spec, config, mesh, kv_axis: str, wps: int,
                 else o for p, o in enumerate(outs)]
         return st, outs
 
-    smapped = compat_shard_map(
-        body, mesh,
-        in_specs=(P(kv_axis, None), (P(),) * 4),
-        out_specs=(P(kv_axis, None), [P()] * 4))
+    smapped = jax.shard_map(
+        body, mesh=mesh, in_specs=(P(kv_axis, None), (P(),) * 4),
+        out_specs=(P(kv_axis, None), [P()] * 4), check_vma=False)
     return jax.jit(smapped)
 
 
@@ -243,7 +242,7 @@ class PagedPool:
     kv_axis: str = "kv"
     spec_local: Optional[MemorySpec] = None   # per-shard geometry (mesh only)
     use_kernel: bool = False
-    interpret: bool = True
+    interpret: bool | None = None
     traversals: int = 0                # physical pool traversals serviced
     seq_tile: int = 0                  # words per accounting tile
     tile_reads: int = 0                # distinct R-port tiles touched
@@ -278,8 +277,8 @@ class PagedPool:
 
     @classmethod
     def create(cls, *, n_pages: int, page_tokens: int, word_width: int,
-               dtype=jnp.float32, num_banks: int = 8,
-               use_kernel: bool = False, interpret: bool = True,
+               dtype=jnp.float32, num_banks: Optional[int] = None,
+               use_kernel: bool = False, interpret: bool | None = None,
                seq_tile: int = 0, kv_shards: int = 1, mesh=None,
                kv_axis: str = "kv") -> "PagedPool":
         if mesh is not None:
@@ -296,17 +295,25 @@ class PagedPool:
         plan = kv_shard_plan(kv_shards, n_pages=n_pages,
                              page_tokens=page_tokens)
         num_words = plan.num_words
-        while num_words % num_banks:
-            num_banks //= 2                       # geometry guard
-        num_banks = max(num_banks, 1)
         # Mosaic lane alignment: the STORAGE word is padded to a whole lane
         # count (word_pad) so the banked kernel's [wpb, W] tiles keep a
         # 128-multiple minor dim at CI's small word widths too; callers keep
         # reading/writing ``word_width``-wide vectors (the pad lanes are
         # zero and cropped on the way out)
-        spec = MemorySpec(num_words=num_words,
-                          word_width=word_pad(word_width), dtype=dtype,
-                          num_banks=num_banks)
+        width = word_pad(word_width)
+
+        def banks(words: int) -> int:
+            # the bank count follows the VMEM budget for this word width
+            # unless the caller fixed one (then only the divisibility guard)
+            if num_banks is None:
+                return bank_count(words, width * jnp.dtype(dtype).itemsize)
+            nb = num_banks
+            while words % nb:
+                nb //= 2
+            return max(nb, 1)
+
+        spec = MemorySpec(num_words=num_words, word_width=width, dtype=dtype,
+                          num_banks=banks(num_words))
         storage = spec.init_storage()
         spec_local = None
         if mesh is not None and kv_shards > 1:
@@ -315,12 +322,8 @@ class PagedPool:
                                  page_tokens=page_tokens, axis=kv_axis)
             storage = jax.device_put(storage, NamedSharding(mesh, pspec))
             wps = plan.words_per_shard
-            nb_local = num_banks
-            while wps % nb_local:
-                nb_local //= 2
-            spec_local = MemorySpec(num_words=wps,
-                                    word_width=spec.word_width, dtype=dtype,
-                                    num_banks=max(nb_local, 1))
+            spec_local = MemorySpec(num_words=wps, word_width=width,
+                                    dtype=dtype, num_banks=banks(wps))
         return cls(spec=spec, page_tokens=page_tokens, storage=storage,
                    free_by_shard=[list(range(s * plan.pages_per_shard,
                                              (s + 1) * plan.pages_per_shard))

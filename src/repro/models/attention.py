@@ -134,7 +134,7 @@ def attention_prefill_chunk(p: dict, x: jax.Array, offset: jax.Array,
                             kernel_mode: Literal["reference", "multiport"] = "reference",
                             seq_tile: int = 128,
                             dynamic_grid: bool = False,
-                            interpret: bool = True,
+                            interpret: bool | None = None,
                             mesh=None, mesh_axis: str = "kv",
                             port_mix: str = "wr",
                             compute_dtype=None):
@@ -190,7 +190,7 @@ def attention_decode(p: dict, x: jax.Array, cache_k: jax.Array,
                      kernel_mode: Literal["reference", "multiport"] = "reference",
                      seq_tile: int = 128, length_mask: bool = True,
                      dynamic_grid: bool = False, num_kv_splits: int = 1,
-                     interpret: bool = True,
+                     interpret: bool | None = None,
                      mesh=None, mesh_axis: str = "kv",
                      port_mix: str = "wr",
                      compute_dtype=None):

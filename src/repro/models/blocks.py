@@ -79,7 +79,7 @@ def transformer_block_prefill_chunk(p: dict, x, offset, chunk_len,
                                     kernel_mode: str = "reference",
                                     seq_tile: int = 128,
                                     dynamic_grid: bool = False,
-                                    interpret: bool = True,
+                                    interpret: bool | None = None,
                                     mesh=None, mesh_axis: str = "kv",
                                     port_mix: str = "wr"):
     h, ck, cv = A.attention_prefill_chunk(
@@ -105,7 +105,7 @@ def transformer_block_decode(p: dict, x, cache_k, cache_v, cache_len,
                              seq_tile: int = 128, length_mask: bool = True,
                              dynamic_grid: bool = False,
                              num_kv_splits: int = 1,
-                             interpret: bool = True,
+                             interpret: bool | None = None,
                              mesh=None, mesh_axis: str = "kv",
                              port_mix: str = "wr"):
     h, ck, cv = A.attention_decode(

@@ -240,7 +240,7 @@ def decode_step(params: PyTree, cfg: ArchConfig, state: PyTree, batch: dict,
                 *, kernel_mode: str = "reference", seq_tile: int = 128,
                 length_mask: bool = True, dynamic_grid: bool = False,
                 num_kv_splits: int = 1,
-                interpret: bool = True, mesh=None,
+                interpret: bool | None = None, mesh=None,
                 mesh_axis: str = "kv",
                 port_mix: str = "wr") -> tuple[PyTree, jax.Array]:
     """Returns (state', logits [B, V]).
@@ -396,7 +396,7 @@ def prefill(params: PyTree, cfg: ArchConfig, state: PyTree, batch: dict
 
 def prefill_chunk(params: PyTree, cfg: ArchConfig, state: PyTree, batch: dict,
                   *, kernel_mode: str = "reference", seq_tile: int = 128,
-                  dynamic_grid: bool = False, interpret: bool = True,
+                  dynamic_grid: bool = False, interpret: bool | None = None,
                   mesh=None, mesh_axis: str = "kv", port_mix: str = "wr"
                   ) -> tuple[PyTree, jax.Array]:
     """Process ONE fixed-size prompt chunk for a batch of sequences.

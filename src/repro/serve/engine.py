@@ -77,9 +77,9 @@ the kernels skip tiles past each sequence's own live length under
 tiles actually touched; ``steady_decode_tile_bound`` is the ideal
 ``ceil((cache_len+1)/seq_tile)`` budget the CI bench gate checks against.
 
-``interpret=True`` (default) executes the Pallas kernels in Python — the
-CPU-CI escape hatch; pass ``False`` on TPU deployments to lower through
-Mosaic.
+``interpret`` defaults to None: the Pallas kernels run in the Pallas
+interpreter on a CPU backend and compile through Mosaic on a TPU (the one
+choice lives in ``kernels.tiling.resolve_interpret``).
 
 **Async host loop** (this revision): host-side admission/scheduling is
 decoupled from device macro-cycles. Admission lives in its own
@@ -161,15 +161,6 @@ EVICT, PREFILL, DECODE, STATUS = 0, 1, 2, 3
 # can issue on (the engine's phase -> pool-port wiring)
 _STREAM_KEY = {SCRUB: "scrub", BULK_FILL: "prefill",
                APPEND: "append", ATTN_READ: "read"}
-
-
-def _jit_traces(fn) -> int:
-    """Compiled-trace count of a ``jax.jit`` callable (-1 when the running
-    jax version does not expose the cache probe)."""
-    try:
-        return fn._cache_size()
-    except AttributeError:
-        return -1
 
 
 @dataclasses.dataclass
@@ -289,7 +280,7 @@ class MultiPortEngine:
                  kernel_mode: str = "pallas", single_port: bool = False,
                  greedy: bool = True, page_tokens: int = 8,
                  seq_tile: int = 128, length_bound: bool = True,
-                 dynamic_grid: bool = True, interpret: bool = True,
+                 dynamic_grid: bool = True, interpret: bool | None = None,
                  num_kv_splits: int = 1,
                  mesh=None, kv_axis: str = "kv",
                  schedule_mode: str = "ooo", max_ports: int = MAX_PORTS,
@@ -336,7 +327,6 @@ class MultiPortEngine:
         self.chunk_tokens = chunk_tokens or prefill_bucket
         self.kernel_mode = kernel_mode
         self.single_port = single_port
-        self.interpret = interpret
         # length-bounded traversals: staging caches (and so the Pallas
         # kernels' tile grids) cover the batch's LIVE length rounded up to a
         # power-of-two count of seq_tile tiles, not the allocated max_len.
@@ -535,12 +525,12 @@ class MultiPortEngine:
         """Times the jitted decode step has been (re)traced — 1 on the
         dynamic-grid path regardless of cache length; O(log S_max/seq_tile)
         ladder buckets on the bucketed fallback."""
-        return _jit_traces(self._decode)
+        return self._decode._cache_size()
 
     @property
     def prefill_traces(self) -> int:
         """Times the jitted chunked-prefill step has been (re)traced."""
-        return _jit_traces(self._prefill_chunk)
+        return self._prefill_chunk._cache_size()
 
     @property
     def n_slots(self) -> int:

@@ -43,13 +43,13 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(step)(state, batch)
 
         # sharded result on (2, 4) mesh
-        from repro.launch.mesh import make_mesh, use_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules = shd.Rules.for_mesh(mesh)
         st_shapes = jax.eval_shape(lambda: state)
         st_specs = SP.train_state_pspecs(cfg, mesh, rules, st_shapes)
         bspecs = shd.batch_specs(cfg, mesh, rules, global_batch=8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jf = jax.jit(step,
                          in_shardings=(SP.named_tree(mesh, st_specs),
                                        SP.named_tree(mesh, bspecs)),
@@ -114,15 +114,15 @@ def test_compressed_train_step_converges_and_int8_on_wire():
         loader = ShardedLoader(cfg, DataConfig(seed=2), batch=8, seq=16)
         step = make_train_step(cfg, tcfg)
 
-        from repro.launch.mesh import make_mesh, use_mesh
-        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rules = shd.Rules.for_mesh(mesh)
         st_shapes = jax.eval_shape(lambda: state)
         st_specs = SP.train_state_pspecs(cfg, mesh, rules, st_shapes)
         bspecs = shd.batch_specs(cfg, mesh, rules, global_batch=8)
         state = jax.device_put(state, SP.named_tree(mesh, st_specs))
         bshard = SP.named_tree(mesh, bspecs)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jf = jax.jit(step, in_shardings=(SP.named_tree(mesh, st_specs),
                                              SP.named_tree(mesh, bspecs)),
                          out_shardings=(SP.named_tree(mesh, st_specs), None))
@@ -160,31 +160,32 @@ def test_elastic_reshard_between_meshes():
         loader = ShardedLoader(cfg, DataConfig(seed=1), batch=8, seq=16)
         step = make_train_step(cfg, tcfg)
 
-        from repro.launch.mesh import make_mesh, use_mesh
-        mesh8 = make_mesh((2, 4), ("data", "model"))
+        mesh8 = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules8 = shd.Rules.for_mesh(mesh8)
         st_shapes = jax.eval_shape(lambda: state)
         specs8 = SP.train_state_pspecs(cfg, mesh8, rules8, st_shapes)
         state8 = jax.device_put(state, SP.named_tree(mesh8, specs8))
-        with use_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             jf8 = jax.jit(step, in_shardings=(SP.named_tree(mesh8, specs8), None),
                           out_shardings=(SP.named_tree(mesh8, specs8), None))
             s8, _ = jf8(state8, loader.get(0))
         ckpt.save("/tmp/elastic_ck", 0, s8)
 
         # "pod loss": restart on a 4-device mesh, restore + reshard
-        mesh4 = make_mesh((2, 2), ("data", "model"))
+        mesh4 = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules4 = shd.Rules.for_mesh(mesh4)
         specs4 = SP.train_state_pspecs(cfg, mesh4, rules4, st_shapes)
         restored, _ = ckpt.restore("/tmp/elastic_ck", st_shapes,
                                    shardings=SP.named_tree(mesh4, specs4))
-        with use_mesh(mesh4):
+        with jax.set_mesh(mesh4):
             jf4 = jax.jit(step, in_shardings=(SP.named_tree(mesh4, specs4), None),
                           out_shardings=(SP.named_tree(mesh4, specs4), None))
             s4, m4 = jf4(restored, loader.get(1))
 
         # reference: continue on the 8-device mesh
-        with use_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             s8b, m8 = jf8(s8, loader.get(1))
         np.testing.assert_allclose(float(m4["ce"]), float(m8["ce"]), rtol=1e-5)
         print("ELASTIC-OK")

@@ -76,8 +76,6 @@ def test_one_traversal_regardless_of_port_count():
                                                     interpret=True))
         lowered = f.lower(storage, reqs)
         cost = lowered.compile().cost_analysis()
-        if isinstance(cost, list):        # pre-0.5 JAX returns [dict]
-            cost = cost[0]
         return cost.get("bytes accessed", 0.0)
 
     b1, b4 = kernel_storage_bytes(1), kernel_storage_bytes(4)

@@ -110,11 +110,15 @@ def test_fit_seq_tile():
 
 def test_fused_prefill_chunk_property(rng):
     """Property (CI installs the ``dev`` extra; skips locally): kernel ==
-    oracle over random offset / chunk_len / seq_tile / S_max."""
+    oracle over random offset / chunk_len / seq_tile / S_max. Derandomized,
+    so every run draws the same examples; the explicit example is the
+    counterexample that once failed at random: all rows empty
+    (``chunk_len == 0``) and a live bound that stopped at ``offset``, short
+    of the position such a row's padded queries attend."""
     hyp = pytest.importorskip("hypothesis")
     st = pytest.importorskip("hypothesis.strategies")
 
-    @hyp.settings(max_examples=20, deadline=None,
+    @hyp.settings(max_examples=20, deadline=None, derandomize=True,
                   suppress_health_check=[hyp.HealthCheck.too_slow])
     @hyp.given(
         b=st.integers(1, 3),
@@ -124,17 +128,18 @@ def test_fused_prefill_chunk_property(rng):
         g=st.sampled_from([1, 2]),
         seq_tile=st.sampled_from([1, 4, 8, 16, 128]),
         seed=st.integers(0, 2**31 - 1),
-        data=st.data())
-    def prop(b, c, s_extra, hkv, g, seq_tile, seed, data):
+        live_extra=st.one_of(st.none(), st.integers(0, 48)))
+    @hyp.example(b=3, c=1, s_extra=4, hkv=1, g=2, seq_tile=4,
+                 seed=329690261, live_extra=0)
+    def prop(b, c, s_extra, hkv, g, seq_tile, seed, live_extra):
         s = c + s_extra                      # S_max always fits the chunk
         d = 8
         r = np.random.default_rng(seed)
         q, ck, cv, nk, nv, off, cl = _case(r, b, c, s, hkv, g, d)
-        # any live bound covering the written range must be transparent
-        need = int(np.max(np.asarray(off) + np.asarray(cl)))
-        live = data.draw(st.one_of(st.none(),
-                                   st.integers(max(need, 1), s + 8)),
-                         label="live_len")
+        # any live bound covering every row's attended range must be
+        # transparent; a row with no chunk rows still attends ``offset``
+        need = int(np.max(np.asarray(off) + np.maximum(np.asarray(cl), 1)))
+        live = None if live_extra is None else min(need + live_extra, s + 8)
         _assert_matches(q, ck, cv, nk, nv, off, cl, seq_tile=seq_tile,
                         live_len=live)
 

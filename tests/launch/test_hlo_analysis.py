@@ -99,8 +99,8 @@ def test_collectives_counted_with_loop_expansion():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch import hlo_analysis as HA
-        from repro.launch.mesh import make_mesh, use_mesh
-        mesh = make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 1)
         def body(c, wl):
             return jnp.tanh(c @ wl), None
         def f(x, ws):
@@ -108,7 +108,7 @@ def test_collectives_counted_with_loop_expansion():
             return y
         x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         ws = jax.ShapeDtypeStruct((5, 64, 64), jnp.float32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jf = jax.jit(f, in_shardings=(
                 NamedSharding(mesh, P(None, "model")),
                 NamedSharding(mesh, P(None, "model", None))))
