@@ -56,8 +56,8 @@ def main():
     print(f"pool: {eng.pool_traversals} physical traversals "
           f"({eng.steady_decode_traversals / max(eng.steady_decode_steps, 1):.2f}"
           f" per steady decode step; claim C1: ~1 fused vs 2 two-pass)")
-    print("port schedule of the first 6 cycles:",
-          [tuple("EPDS"[p] for p in c) for c in eng.port_log[:6]])
+    print("port schedule of the first 6 logged cycles:",
+          [tuple("EPDS"[p] for p in c) for c in list(eng.port_log)[:6]])
 
 
 if __name__ == "__main__":

@@ -443,6 +443,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         out_k, out_v, out, tiles = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
+            name="fused_decode_attention",
             out_shape=[
                 jax.ShapeDtypeStruct(ck_w.shape, ck_w.dtype),
                 jax.ShapeDtypeStruct(cv_w.shape, cv_w.dtype),
@@ -488,6 +489,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         out_k, out_v, acc, stats, tiles = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
+            name="fused_decode_attention_split",
             out_shape=[
                 jax.ShapeDtypeStruct(ck_w.shape, ck_w.dtype),
                 jax.ShapeDtypeStruct(cv_w.shape, cv_w.dtype),
@@ -501,6 +503,7 @@ def fused_append_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         out = pl.pallas_call(
             functools.partial(_combine_kernel, num_kv_splits=ns),
             grid=(b,),
+            name="fused_decode_attention_combine",
             in_specs=[
                 pl.BlockSpec(blocks["acc_partial"], lambda bb: (bb, 0, 0)),
                 pl.BlockSpec(blocks["lse_partial"], lambda bb: (bb, 0, 0)),

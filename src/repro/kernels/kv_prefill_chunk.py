@@ -272,6 +272,7 @@ def fused_chunk_append_attend(q: jax.Array, cache_k: jax.Array,
     out_k, out_v, out, tiles = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="fused_prefill_chunk_attention",
         out_shape=[
             jax.ShapeDtypeStruct(ck_w.shape, ck_w.dtype),
             jax.ShapeDtypeStruct(cv_w.shape, cv_w.dtype),

@@ -131,6 +131,7 @@ def multiport_sram_step(storage_banked: jax.Array, addr: jax.Array,
     out_storage, reads = pl.pallas_call(
         kernel,
         grid=(w // ct, nb),
+        name="multiport_pool_step",
         in_specs=[
             pl.BlockSpec((p_eff, 1, q), lambda j, b: (0, 0, 0)),   # addr rows
             pl.BlockSpec((p_eff, q, ct), lambda j, b: (0, 0, j)),  # data

@@ -90,6 +90,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import (MemorySpec, PortConfig, READ, WRITE, PortRequest,
                         empty_request, step, step_banked)
 from repro.distributed.sharding import (KVShardPlan, kv_pool_spec,
@@ -799,7 +800,10 @@ class PagedPool:
         read, not a ported traversal (the pool's ports only carry words
         the model is writing or attending this macro-cycle)."""
         addr = self._addr(seq, np.asarray(positions))
-        got = np.asarray(self.storage[jnp.asarray(addr)], np.float32)
+        with obs.span("engine.pool.gather") as c:
+            words = self.storage[jnp.asarray(addr)]
+            got = np.asarray(words, np.float32)
+            c["d2h_bytes"] = words.nbytes
         return got[:, :self.io_width]
 
     def _cow_prepare(self, seq: int, new_tokens: int):
@@ -922,141 +926,156 @@ class PagedPool:
         Sharded pools (a real mesh) run the traversal under ``shard_map``:
         every shard concurrently services its own address range and read
         lanes psum — still ONE traversal of (now distributed) storage.
+
+        Traced as the ``engine.pool.issue`` span (``repro.obs``), with the
+        live lanes and the bytes the port requests upload.
         """
-        read_was_dict = isinstance(read, dict)
-        appends = self._as_streams(append)
-        reads = self._as_streams(read)
-        prefills = self._as_streams(prefill)
-        scrub = list(scrub) if scrub else []
-        priority = _PRIORITY if priority is None else tuple(priority)
+        with obs.span("engine.pool.issue") as counts:
+            read_was_dict = isinstance(read, dict)
+            appends = self._as_streams(append)
+            reads = self._as_streams(read)
+            prefills = self._as_streams(prefill)
+            scrub = list(scrub) if scrub else []
+            priority = _PRIORITY if priority is None else tuple(priority)
 
-        # program order: bulk prefills grow tables before decode appends,
-        # matching the scheduler's footprint projection
-        self._check_capacity(prefills + appends, reads)
+            # program order: bulk prefills grow tables before decode appends,
+            # matching the scheduler's footprint projection
+            self._check_capacity(prefills + appends, reads)
 
-        # copy-on-write remaps commit here (prefills before appends, the
-        # projection's order): each shared tail page a write stream would
-        # touch is replaced by a fresh home-shard page whose live words
-        # ride the SAME traversal's W port as extra lanes
-        cow_fill = [c for c in (self._cow_prepare(s["seq"],
-                                                  int(s["vectors"].shape[0]))
-                                for s in prefills) if c is not None]
-        cow_app = [c for c in (self._cow_prepare(s["seq"],
-                                                 int(s["vectors"].shape[0]))
-                               for s in appends) if c is not None]
+            # copy-on-write remaps commit here (prefills before appends, the
+            # projection's order): each shared tail page a write stream would
+            # touch is replaced by a fresh home-shard page whose live words
+            # ride the SAME traversal's W port as extra lanes
+            cow_fill = [c for c in (self._cow_prepare(s["seq"],
+                                                      int(s["vectors"].shape[0]))
+                                    for s in prefills) if c is not None]
+            cow_app = [c for c in (self._cow_prepare(s["seq"],
+                                                     int(s["vectors"].shape[0]))
+                                   for s in appends) if c is not None]
 
-        lanes = [0, 0, 0, 0]
-        lanes[APPEND] = (sum(s["vectors"].shape[0] for s in appends)
-                         + sum(len(o) for o, _ in cow_app))
-        lanes[ATTN_READ] = sum(len(s["positions"]) for s in reads)
-        lanes[BULK_FILL] = (sum(s["vectors"].shape[0] for s in prefills)
-                            + sum(len(o) for o, _ in cow_fill))
-        lanes[SCRUB] = len(scrub) * self.page_tokens
-        if not any(lanes):
-            # no traffic: still mirror the read input shape (one result per
-            # stream) so stream->result pairing survives empty gathers
+            lanes = [0, 0, 0, 0]
+            lanes[APPEND] = (sum(s["vectors"].shape[0] for s in appends)
+                             + sum(len(o) for o, _ in cow_app))
+            lanes[ATTN_READ] = sum(len(s["positions"]) for s in reads)
+            lanes[BULK_FILL] = (sum(s["vectors"].shape[0] for s in prefills)
+                                + sum(len(o) for o, _ in cow_fill))
+            lanes[SCRUB] = len(scrub) * self.page_tokens
+            if not any(lanes):
+                # no traffic: still mirror the read input shape (one result per
+                # stream) so stream->result pairing survives empty gathers
+                if not reads:
+                    return {"read": None}
+                empty = jnp.zeros((0, self.io_width), self.spec.dtype)
+                return {"read": empty if read_was_dict
+                        else [empty for _ in reads]}
+            q = _bucket(max(lanes))
+
+            reqs = [empty_request(q, self.spec.word_width, self.spec.dtype)
+                    for _ in range(4)]
+            w_tiles: set = set()               # distinct W-port tiles this cycle
+            r_tiles: set = set()               # distinct R-port tiles this cycle
+            word_bytes = (np.dtype(self.spec.dtype).itemsize
+                          * self.spec.word_width)
+            uploaded = 0                       # bytes the port requests upload
+
+            def _write_req(streams, cow=()):
+                nonlocal uploaded
+                addr = np.zeros(q, np.int32)
+                data = np.zeros((q, self.spec.word_width), np.float32)
+                mask = np.zeros(q, bool)
+                at = 0
+                for old, new in cow:
+                    # CoW copy lanes: the shared page's live words, gathered
+                    # host-side (it cannot be a ported read — the copy must
+                    # land in the same traversal), written to the fresh page.
+                    # Disjoint from the stream's own words (those start at the
+                    # copied offset), so lane order never matters.
+                    with obs.span("engine.pool.gather") as c:
+                        shared = self.storage[jnp.asarray(old)]
+                        vals = np.asarray(shared, np.float32)
+                        c["d2h_bytes"] = shared.nbytes
+                    n = len(new)
+                    addr[at:at + n] = new
+                    data[at:at + n] = vals
+                    mask[at:at + n] = True
+                    at += n
+                for s in streams:
+                    seq, vec = s["seq"], np.asarray(s["vectors"], np.float32)
+                    t = vec.shape[0]
+                    self._ensure_capacity(seq, t)
+                    idx = np.arange(self.lengths[seq], self.lengths[seq] + t)
+                    addr[at:at + t] = self._addr(seq, idx)
+                    data[at:at + t, :vec.shape[1]] = vec    # pad lanes stay zero
+                    mask[at:at + t] = True
+                    self.lengths[seq] += t
+                    at += t
+                w_tiles.update(np.unique(addr[:at] // self.seq_tile).tolist())
+                uploaded += addr.nbytes + q * word_bytes + mask.nbytes
+                return PortRequest(addr=jnp.asarray(addr),
+                                   data=jnp.asarray(data, self.spec.dtype),
+                                   mask=jnp.asarray(mask))
+
+            if prefills:
+                reqs[BULK_FILL] = _write_req(prefills, cow_fill)
+            if appends:
+                reqs[APPEND] = _write_req(appends, cow_app)
+            if scrub:
+                addr = np.zeros(q, np.int32)
+                mask = np.zeros(q, bool)
+                words = (np.asarray(scrub)[:, None] * self.page_tokens
+                         + np.arange(self.page_tokens)[None, :]).reshape(-1)
+                addr[: len(words)] = words
+                mask[: len(words)] = True
+                w_tiles.update(np.unique(words // self.seq_tile).tolist())
+                uploaded += addr.nbytes + mask.nbytes
+                reqs[SCRUB] = PortRequest(
+                    addr=jnp.asarray(addr),
+                    data=jnp.zeros((q, self.spec.word_width), self.spec.dtype),
+                    mask=jnp.asarray(mask))
+            slices = []
+            if reads:
+                addr = np.zeros(q, np.int32)
+                mask = np.zeros(q, bool)
+                at = 0
+                for s in reads:
+                    pos = np.asarray(s["positions"])
+                    addr[at:at + len(pos)] = self._addr(s["seq"], pos)
+                    mask[at:at + len(pos)] = True
+                    slices.append((at, at + len(pos)))
+                    at += len(pos)
+                r_tiles.update(np.unique(addr[:at] // self.seq_tile).tolist())
+                uploaded += addr.nbytes + mask.nbytes
+                reqs[ATTN_READ] = PortRequest(
+                    addr=jnp.asarray(addr),
+                    data=jnp.zeros((q, self.spec.word_width), self.spec.dtype),
+                    mask=jnp.asarray(mask))
+
+            counts["lanes"] = sum(lanes)
+            counts["h2d_bytes"] = uploaded
+            cfg = PortConfig(enabled=(bool(appends), bool(reads), bool(prefills),
+                                      bool(scrub)),
+                             roles=_ROLES, priority=priority)
+            self.mix_counts[cfg.describe()] = self.mix_counts.get(
+                cfg.describe(), 0) + 1
+            if self.mesh is not None and self.kv_shards > 1:
+                fn = _sharded_pool_step(self.spec_local, cfg, self.mesh,
+                                        self.kv_axis, self.words_per_shard,
+                                        self.use_kernel, self.interpret)
+                self.storage, out = fn(self.storage, tuple(reqs))
+            else:
+                self.storage, out = _pool_step(self.spec, cfg, self.storage,
+                                               tuple(reqs),
+                                               use_kernel=self.use_kernel,
+                                               interpret=self.interpret)
+            self.traversals += 1
+            self.tile_writes += self._count_tiles(w_tiles,
+                                                  self.tile_writes_by_shard)
+            self.tile_reads += self._count_tiles(r_tiles,
+                                                 self.tile_reads_by_shard)
             if not reads:
                 return {"read": None}
-            empty = jnp.zeros((0, self.io_width), self.spec.dtype)
-            return {"read": empty if read_was_dict
-                    else [empty for _ in reads]}
-        q = _bucket(max(lanes))
-
-        reqs = [empty_request(q, self.spec.word_width, self.spec.dtype)
-                for _ in range(4)]
-        w_tiles: set = set()               # distinct W-port tiles this cycle
-        r_tiles: set = set()               # distinct R-port tiles this cycle
-
-        def _write_req(streams, cow=()):
-            addr = np.zeros(q, np.int32)
-            data = np.zeros((q, self.spec.word_width), np.float32)
-            mask = np.zeros(q, bool)
-            at = 0
-            for old, new in cow:
-                # CoW copy lanes: the shared page's live words, gathered
-                # host-side (it cannot be a ported read — the copy must
-                # land in the same traversal), written to the fresh page.
-                # Disjoint from the stream's own words (those start at the
-                # copied offset), so lane order never matters.
-                vals = np.asarray(self.storage[jnp.asarray(old)],
-                                  np.float32)
-                n = len(new)
-                addr[at:at + n] = new
-                data[at:at + n] = vals
-                mask[at:at + n] = True
-                at += n
-            for s in streams:
-                seq, vec = s["seq"], np.asarray(s["vectors"], np.float32)
-                t = vec.shape[0]
-                self._ensure_capacity(seq, t)
-                idx = np.arange(self.lengths[seq], self.lengths[seq] + t)
-                addr[at:at + t] = self._addr(seq, idx)
-                data[at:at + t, :vec.shape[1]] = vec    # pad lanes stay zero
-                mask[at:at + t] = True
-                self.lengths[seq] += t
-                at += t
-            w_tiles.update(np.unique(addr[:at] // self.seq_tile).tolist())
-            return PortRequest(addr=jnp.asarray(addr),
-                               data=jnp.asarray(data, self.spec.dtype),
-                               mask=jnp.asarray(mask))
-
-        if prefills:
-            reqs[BULK_FILL] = _write_req(prefills, cow_fill)
-        if appends:
-            reqs[APPEND] = _write_req(appends, cow_app)
-        if scrub:
-            addr = np.zeros(q, np.int32)
-            mask = np.zeros(q, bool)
-            words = (np.asarray(scrub)[:, None] * self.page_tokens
-                     + np.arange(self.page_tokens)[None, :]).reshape(-1)
-            addr[: len(words)] = words
-            mask[: len(words)] = True
-            w_tiles.update(np.unique(words // self.seq_tile).tolist())
-            reqs[SCRUB] = PortRequest(
-                addr=jnp.asarray(addr),
-                data=jnp.zeros((q, self.spec.word_width), self.spec.dtype),
-                mask=jnp.asarray(mask))
-        slices = []
-        if reads:
-            addr = np.zeros(q, np.int32)
-            mask = np.zeros(q, bool)
-            at = 0
-            for s in reads:
-                pos = np.asarray(s["positions"])
-                addr[at:at + len(pos)] = self._addr(s["seq"], pos)
-                mask[at:at + len(pos)] = True
-                slices.append((at, at + len(pos)))
-                at += len(pos)
-            r_tiles.update(np.unique(addr[:at] // self.seq_tile).tolist())
-            reqs[ATTN_READ] = PortRequest(
-                addr=jnp.asarray(addr),
-                data=jnp.zeros((q, self.spec.word_width), self.spec.dtype),
-                mask=jnp.asarray(mask))
-
-        cfg = PortConfig(enabled=(bool(appends), bool(reads), bool(prefills),
-                                  bool(scrub)),
-                         roles=_ROLES, priority=priority)
-        self.mix_counts[cfg.describe()] = self.mix_counts.get(
-            cfg.describe(), 0) + 1
-        if self.mesh is not None and self.kv_shards > 1:
-            fn = _sharded_pool_step(self.spec_local, cfg, self.mesh,
-                                    self.kv_axis, self.words_per_shard,
-                                    self.use_kernel, self.interpret)
-            self.storage, out = fn(self.storage, tuple(reqs))
-        else:
-            self.storage, out = _pool_step(self.spec, cfg, self.storage,
-                                           tuple(reqs),
-                                           use_kernel=self.use_kernel,
-                                           interpret=self.interpret)
-        self.traversals += 1
-        self.tile_writes += self._count_tiles(w_tiles,
-                                              self.tile_writes_by_shard)
-        self.tile_reads += self._count_tiles(r_tiles,
-                                             self.tile_reads_by_shard)
-        if not reads:
-            return {"read": None}
-        got = [out[ATTN_READ][a:b, :self.io_width] for a, b in slices]
-        return {"read": got[0] if read_was_dict else got}
+            got = [out[ATTN_READ][a:b, :self.io_width] for a, b in slices]
+            return {"read": got[0] if read_was_dict else got}
 
     def cycle_batch(self, groups: Sequence[tuple]) -> list:
         """Issue one macro-cycle's SCHEDULE of traversals: ``groups`` is an

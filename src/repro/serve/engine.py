@@ -124,7 +124,7 @@ divides across the mesh) and both fused kernels launch under ``shard_map``:
 each device's kernel prefetches only its own sequences' SMEM scalars and
 bounds its own dynamic tile grid with ITS max live length — a device
 serving short sequences traverses fewer tiles than one serving long
-sequences, which ``decode_tile_reads_by_dev`` (and the bench's v4
+sequences, which ``steady_decode_tile_reads_by_dev`` (and the bench's v4
 per-device balance column) makes visible. ``PagedPool.cycle`` runs the
 pool traversal under ``shard_map`` too (per-shard address windows, psum'd
 read lanes). Greedy decode stays token-identical to the single-device
@@ -133,6 +133,7 @@ path at every device count, in both kernel modes — ``kernel_mode=
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Optional
@@ -141,6 +142,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core import fsm
 from repro.core.clockgen import build_schedule
@@ -156,6 +158,7 @@ from repro.serve.admission import (AdmissionQueue, OverloadController,
 from repro.serve.scheduler import PhaseTxn, PortTxn
 
 EVICT, PREFILL, DECODE, STATUS = 0, 1, 2, 3
+LOG_CYCLES = 1024        # cycles the per-cycle port and schedule logs keep
 
 # pool-port stream keyword for each physical port a scheduled transaction
 # can issue on (the engine's phase -> pool-port wiring)
@@ -194,6 +197,7 @@ class Request:
     finish_tick: Optional[int] = None
     finish_cycle: Optional[int] = None
     t_submit: float = 0.0
+    t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_finish: Optional[float] = None
 
@@ -461,21 +465,20 @@ class MultiPortEngine:
         # accumulation chain (longest row's tiles; / num_kv_splits + 1
         # under split-KV) — the steady-step LATENCY proxy the bench's
         # split-speedup gate reads, vs tile_reads' total-traffic proxy
-        self.decode_critical_tiles = 0
         self.steady_decode_critical_tiles = 0
         self.prefill_tile_reads = 0
-        # per-device attribution of the same R-port tiles (device = the
+        # per-device attribution of the steady R-port tiles (device = the
         # sequence's home shard == its kernel shard): the balance surface
         # the bench's v4 per-device column reads
-        self.decode_tile_reads_by_dev = [0] * self.n_kv_shards
         self.steady_decode_tile_reads_by_dev = [0] * self.n_kv_shards
-        self.prefill_tile_reads_by_dev = [0] * self.n_kv_shards
-        self.port_log: list[tuple[int, ...]] = []
-        # per-cycle schedule observability: which phases shared which pool
-        # traversal (one tuple of phase-id tuples per cycle), how many
-        # cycles carried >1 pool phase, and how many of those the scheduler
-        # packed into a shared traversal
-        self.schedule_log: list[tuple] = []
+        # the last LOG_CYCLES cycles' enabled ports, and their schedule:
+        # which phases shared which pool traversal (one tuple of phase-id
+        # tuples per cycle); how many cycles carried >1 pool phase, and
+        # how many of those the scheduler packed into a shared traversal
+        self.port_log: collections.deque = collections.deque(
+            maxlen=LOG_CYCLES)
+        self.schedule_log: collections.deque = collections.deque(
+            maxlen=LOG_CYCLES)
         self.multi_phase_cycles = 0
         self.coscheduled_cycles = 0
         self._next_rid = 0
@@ -488,21 +491,22 @@ class MultiPortEngine:
         # reference ignores the mesh (it is the sharded-pool oracle)
         kmesh = mesh if self.n_kv_shards > 1 else None
         nsp = self.num_kv_splits
-        self._decode = jax.jit(
-            lambda p, s, b: decode_step(p, cfg, s, b, kernel_mode=attn_mode,
-                                        seq_tile=tile,
-                                        length_mask=length_bound,
-                                        dynamic_grid=dyn,
-                                        num_kv_splits=nsp,
-                                        interpret=interpret,
-                                        mesh=kmesh, mesh_axis=kv_axis,
-                                        port_mix=pmix))
-        self._prefill_chunk = jax.jit(
-            lambda p, s, b: prefill_chunk(p, cfg, s, b, kernel_mode=attn_mode,
-                                          seq_tile=tile, dynamic_grid=dyn,
-                                          interpret=interpret,
-                                          mesh=kmesh, mesh_axis=kv_axis,
-                                          port_mix=pmix))
+        def decode_program(p, s, b):
+            with jax.named_scope("engine.decode"):
+                return decode_step(p, cfg, s, b, kernel_mode=attn_mode,
+                                   seq_tile=tile, length_mask=length_bound,
+                                   dynamic_grid=dyn, num_kv_splits=nsp,
+                                   interpret=interpret, mesh=kmesh,
+                                   mesh_axis=kv_axis, port_mix=pmix)
+
+        def prefill_program(p, s, b):
+            with jax.named_scope("engine.prefill"):
+                return prefill_chunk(p, cfg, s, b, kernel_mode=attn_mode,
+                                     seq_tile=tile, dynamic_grid=dyn,
+                                     interpret=interpret, mesh=kmesh,
+                                     mesh_axis=kv_axis, port_mix=pmix)
+        self._decode = jax.jit(decode_program)
+        self._prefill_chunk = jax.jit(prefill_program)
 
     # ---- client API --------------------------------------------------------
     @classmethod
@@ -841,202 +845,209 @@ class MultiPortEngine:
         then advance EVERY mid-prefill slot by one fixed-size token chunk.
         Chunks from different requests are stacked into one padded batch, run
         through a single chunked-prefill compute step, and all chunks' K,V
-        become streams of the SAME bulk-write port transaction."""
-        nl, _, hkv, hd = self._kv_dims
-        # arrival-ordered admission wave: only the QUEUE HEAD is ever
-        # eligible (AdmissionQueue.pop_ready) — under slot contention a
-        # freed slot goes to the oldest ready request, never a younger
-        # shorter one (FIFO; no long-prompt starvation). Overload safety
-        # wraps the same loop: a degraded controller caps admissions per
-        # cycle, and each candidate head passes the pool's capacity
-        # precheck BEFORE it is popped — a full home shard parks the head
-        # (retry next cycle, after evictions free pages) instead of
-        # raising mid-admission, and a head that exhausts its retry
-        # budget is shed.
-        now = self.vclock
-        cap = self.overload.cap() if self.overload is not None else None
-        admitted_now = 0
-        reserved = None
-        while self.admission.head_ready(now):
-            if cap is not None and admitted_now >= cap:
-                break
-            head = self.admission.head()
-            if reserved is None:
-                reserved = self._reserved_pages_by_shard()
-            # prefix-aware admission: match BEFORE the capacity precheck,
-            # so matched pages (attachable by refcount bump) never count
-            # as demand and the probe moves to the prefix's shard
-            match, worst = prefix_admission_plan(
-                self.pool, head.prompt, head.max_new,
-                enabled=self.prefix_cache)
-            try:
-                shard = self.pool.admission_precheck(
-                    head.rid, worst, reserved_by_shard=reserved,
-                    prefix=match)
-            except PoolCapacityError:
-                if head.capacity_retries >= self.capacity_retry_limit:
-                    # eviction-aware backoff exhausted: shed (drop_head
-                    # keeps the admitted counter honest)
-                    self.admission.drop_head()
-                    self._shed(head, "capacity")
-                    continue
-                head.capacity_retries += 1
-                self.capacity_parked_cycles += 1
-                break       # park: this cycle's evictions already ran,
-                            # retry after the NEXT cycle frees pages
-            slot = self._free_slot()
-            if slot is None:
-                # a ready arrival waited this cycle on a full slot table
-                self.slot_contention_cycles += 1
-                break
-            req = self.admission.pop_ready(now)
-            admitted_now += 1
-            if req.capacity_retries:
-                self.capacity_recoveries += 1
-            full = match.full_pages if match is not None else 0
-            reserved[shard] += max(
-                0, -(-worst // self.pool.page_tokens) - full)
-            req.slot = slot
-            req.admit_cycle = self.cycles
-            req.admit_tick = now
-            if slot in self._freed_slots_this_cycle:
-                # admission only proceeded because this cycle's EVICT
-                # phase freed the slot — eviction-pressure signal
-                self.evict_pressure_admissions += 1
-            if self.cfg.input_mode == "embeddings":
-                raise NotImplementedError("engine demo serves token models")
-            self.slot_req[slot] = req
-            attached = 0
-            if match is not None:
-                # adopt the matched prefix by refcount bump: the request's
-                # home FOLLOWS the shared pages' shard, its table starts at
-                # the matched pages, and prefill resumes at the tail
-                self.pool.attach_prefix(req.rid, match)
-                attached = match.tokens
-            # device-aware placement: the home shard is fixed at admission
-            # (least-loaded, or the prefix's shard), BEFORE the first page
-            # is carved, so the first chunk's compute can already be
-            # grouped onto its device
-            self.pool.assign_home(req.rid)
-            self.slot_len[slot] = attached
-            ps = _PrefillState(
-                consumed=attached,
-                stage_k=np.zeros((nl, self.max_len, hkv, hd), np.float32),
-                stage_v=np.zeros((nl, self.max_len, hkv, hd), np.float32))
-            if attached:
-                # the chunk compute attends over the STAGED running cache,
-                # not the pool — backfill the stage with the adopted words
-                # (inverse of _kv_words) so the tail's attention sees the
-                # prefix KV it never computed
-                w = self.pool.gather_words(req.rid, np.arange(attached))
-                w = w.reshape(attached, nl, 2, hkv, hd)
-                ps.stage_k[:, :attached] = np.moveaxis(w[:, :, 0], 0, 1)
-                ps.stage_v[:, :attached] = np.moveaxis(w[:, :, 1], 0, 1)
-            self._prefilling[slot] = ps
-        if not self._prefilling:
-            return []
+        become streams of the SAME bulk-write port transaction. Traced as
+        the ``engine.prefill`` span, with the chunk batch's rows and the
+        bytes it moves each way."""
+        with obs.span("engine.prefill") as counts:
+            nl, _, hkv, hd = self._kv_dims
+            # arrival-ordered admission wave: only the QUEUE HEAD is ever
+            # eligible (AdmissionQueue.pop_ready) — under slot contention a
+            # freed slot goes to the oldest ready request, never a younger
+            # shorter one (FIFO; no long-prompt starvation). Overload safety
+            # wraps the same loop: a degraded controller caps admissions per
+            # cycle, and each candidate head passes the pool's capacity
+            # precheck BEFORE it is popped — a full home shard parks the head
+            # (retry next cycle, after evictions free pages) instead of
+            # raising mid-admission, and a head that exhausts its retry
+            # budget is shed.
+            now = self.vclock
+            cap = self.overload.cap() if self.overload is not None else None
+            admitted_now = 0
+            reserved = None
+            while self.admission.head_ready(now):
+                if cap is not None and admitted_now >= cap:
+                    break
+                head = self.admission.head()
+                if reserved is None:
+                    reserved = self._reserved_pages_by_shard()
+                # prefix-aware admission: match BEFORE the capacity precheck,
+                # so matched pages (attachable by refcount bump) never count
+                # as demand and the probe moves to the prefix's shard
+                match, worst = prefix_admission_plan(
+                    self.pool, head.prompt, head.max_new,
+                    enabled=self.prefix_cache)
+                try:
+                    shard = self.pool.admission_precheck(
+                        head.rid, worst, reserved_by_shard=reserved,
+                        prefix=match)
+                except PoolCapacityError:
+                    if head.capacity_retries >= self.capacity_retry_limit:
+                        # eviction-aware backoff exhausted: shed (drop_head
+                        # keeps the admitted counter honest)
+                        self.admission.drop_head()
+                        self._shed(head, "capacity")
+                        continue
+                    head.capacity_retries += 1
+                    self.capacity_parked_cycles += 1
+                    break       # park: this cycle's evictions already ran,
+                                # retry after the NEXT cycle frees pages
+                slot = self._free_slot()
+                if slot is None:
+                    # a ready arrival waited this cycle on a full slot table
+                    self.slot_contention_cycles += 1
+                    break
+                req = self.admission.pop_ready(now)
+                admitted_now += 1
+                if req.capacity_retries:
+                    self.capacity_recoveries += 1
+                full = match.full_pages if match is not None else 0
+                reserved[shard] += max(
+                    0, -(-worst // self.pool.page_tokens) - full)
+                req.slot = slot
+                req.admit_cycle = self.cycles
+                req.admit_tick = now
+                req.t_admit = time.perf_counter()
+                if slot in self._freed_slots_this_cycle:
+                    # admission only proceeded because this cycle's EVICT
+                    # phase freed the slot — eviction-pressure signal
+                    self.evict_pressure_admissions += 1
+                if self.cfg.input_mode == "embeddings":
+                    raise NotImplementedError("engine demo serves token models")
+                self.slot_req[slot] = req
+                attached = 0
+                if match is not None:
+                    # adopt the matched prefix by refcount bump: the request's
+                    # home FOLLOWS the shared pages' shard, its table starts at
+                    # the matched pages, and prefill resumes at the tail
+                    self.pool.attach_prefix(req.rid, match)
+                    attached = match.tokens
+                # device-aware placement: the home shard is fixed at admission
+                # (least-loaded, or the prefix's shard), BEFORE the first page
+                # is carved, so the first chunk's compute can already be
+                # grouped onto its device
+                self.pool.assign_home(req.rid)
+                self.slot_len[slot] = attached
+                ps = _PrefillState(
+                    consumed=attached,
+                    stage_k=np.zeros((nl, self.max_len, hkv, hd), np.float32),
+                    stage_v=np.zeros((nl, self.max_len, hkv, hd), np.float32))
+                if attached:
+                    # the chunk compute attends over the STAGED running cache,
+                    # not the pool — backfill the stage with the adopted words
+                    # (inverse of _kv_words) so the tail's attention sees the
+                    # prefix KV it never computed
+                    w = self.pool.gather_words(req.rid, np.arange(attached))
+                    w = w.reshape(attached, nl, 2, hkv, hd)
+                    ps.stage_k[:, :attached] = np.moveaxis(w[:, :, 0], 0, 1)
+                    ps.stage_v[:, :attached] = np.moveaxis(w[:, :, 1], 0, 1)
+                self._prefilling[slot] = ps
+            if not self._prefilling:
+                return []
 
-        # one padded chunk batch across all prefilling slots (batch dim
-        # bucketed to a power of two so admissions don't retrace the jit);
-        # the staging caches cover a bucketed LIVE prefix, not max_len, so
-        # the chunk kernel's tile grid is bounded by the longest live prefix
-        order = sorted(self._prefilling)
-        # a degraded overload controller shrinks the per-cycle chunk (the
-        # generated tokens are unchanged — chunked prefill is chunk-size
-        # invariant — only the per-cycle port-traffic shape moves)
-        c = (self.overload.chunk_tokens(self.chunk_tokens)
-             if self.overload is not None else self.chunk_tokens)
-        if self.n_kv_shards == 1:
-            nb = _bucket(len(order), lo=1)
-            row_of = {s: j for j, s in enumerate(order)}
-            groups = [list(order)]
-        else:
-            nb, row_of, groups = self._group_rows(order, base=1)
-        need_of = {s: self._prefilling[s].consumed
-                   + min(c, len(self.slot_req[s].prompt)
-                         - self._prefilling[s].consumed) for s in order}
-        stage_s = self._stage_len(max(need_of.values()))
-        live = min(stage_s, self.max_len)   # last bucket may pad past max_len
-        toks = np.zeros((nb, c), np.int32)
-        clen = np.zeros((nb,), np.int32)
-        offs = np.full((nb,), self._dead_row, np.int32)
-        stage_k = self._stage_bufs.get(("prefill", "k"),
-                                       (nl, nb, stage_s, hkv, hd))
-        stage_v = self._stage_bufs.get(("prefill", "v"),
-                                       (nl, nb, stage_s, hkv, hd))
-        for slot in order:
-            j = row_of[slot]
-            ps = self._prefilling[slot]
-            req = self.slot_req[slot]
-            t0 = ps.consumed
-            n = min(c, len(req.prompt) - t0)
-            toks[j, :n] = req.prompt[t0:t0 + n]
-            clen[j] = n
-            offs[j] = t0
-            stage_k[:, j, :live] = ps.stage_k[:, :live]
-            stage_v[:, j, :live] = ps.stage_v[:, :live]
+            # one padded chunk batch across all prefilling slots (batch dim
+            # bucketed to a power of two so admissions don't retrace the jit);
+            # the staging caches cover a bucketed LIVE prefix, not max_len, so
+            # the chunk kernel's tile grid is bounded by the longest live prefix
+            order = sorted(self._prefilling)
+            # a degraded overload controller shrinks the per-cycle chunk (the
+            # generated tokens are unchanged — chunked prefill is chunk-size
+            # invariant — only the per-cycle port-traffic shape moves)
+            c = (self.overload.chunk_tokens(self.chunk_tokens)
+                 if self.overload is not None else self.chunk_tokens)
+            if self.n_kv_shards == 1:
+                nb = _bucket(len(order), lo=1)
+                row_of = {s: j for j, s in enumerate(order)}
+                groups = [list(order)]
+            else:
+                nb, row_of, groups = self._group_rows(order, base=1)
+            need_of = {s: self._prefilling[s].consumed
+                       + min(c, len(self.slot_req[s].prompt)
+                             - self._prefilling[s].consumed) for s in order}
+            stage_s = self._stage_len(max(need_of.values()))
+            live = min(stage_s, self.max_len)   # last bucket may pad past max_len
+            toks = np.zeros((nb, c), np.int32)
+            clen = np.zeros((nb,), np.int32)
+            offs = np.full((nb,), self._dead_row, np.int32)
+            stage_k = self._stage_bufs.get(("prefill", "k"),
+                                           (nl, nb, stage_s, hkv, hd))
+            stage_v = self._stage_bufs.get(("prefill", "v"),
+                                           (nl, nb, stage_s, hkv, hd))
+            for slot in order:
+                j = row_of[slot]
+                ps = self._prefilling[slot]
+                req = self.slot_req[slot]
+                t0 = ps.consumed
+                n = min(c, len(req.prompt) - t0)
+                toks[j, :n] = req.prompt[t0:t0 + n]
+                clen[j] = n
+                offs[j] = t0
+                stage_k[:, j, :live] = ps.stage_k[:, :live]
+                stage_v[:, j, :live] = ps.stage_v[:, :live]
 
-        state = {"len": jnp.asarray(offs),
-                 "cache_k": jnp.asarray(stage_k),
-                 "cache_v": jnp.asarray(stage_v)}
-        st, logits = self._prefill_chunk(self.params, state,
-                                         {"inputs": jnp.asarray(toks),
-                                          "chunk_len": jnp.asarray(clen)})
-        ck, cv = np.asarray(st["cache_k"]), np.asarray(st["cache_v"])
-        lg = np.asarray(logits)
-        # the chunk kernel masks dead tiles per sequence; the jnp reference
-        # reads the whole staged cache densely per chunk
-        touched, _, per_dev, _ = self._tiles_touched(
-            [[need_of[s] for s in g] for g in groups], stage_s,
-            bounded=self._fused_compute)
-        self.prefill_tile_reads += touched
-        for d, t in enumerate(per_dev):
-            self.prefill_tile_reads_by_dev[d] += t
-        self.prefill_chunks += len(order)
+            state = {"len": jnp.asarray(offs),
+                     "cache_k": jnp.asarray(stage_k),
+                     "cache_v": jnp.asarray(stage_v)}
+            st, logits = self._prefill_chunk(self.params, state,
+                                             {"inputs": jnp.asarray(toks),
+                                              "chunk_len": jnp.asarray(clen)})
+            ck, cv = np.asarray(st["cache_k"]), np.asarray(st["cache_v"])
+            lg = np.asarray(logits)
+            counts["rows"] = len(order)
+            counts["h2d_bytes"] = (offs.nbytes + stage_k.nbytes
+                                   + stage_v.nbytes + toks.nbytes
+                                   + clen.nbytes)
+            counts["d2h_bytes"] = ck.nbytes + cv.nbytes + lg.nbytes
+            # the chunk kernel masks dead tiles per sequence; the jnp reference
+            # reads the whole staged cache densely per chunk
+            touched, _, _, _ = self._tiles_touched(
+                [[need_of[s] for s in g] for g in groups], stage_s,
+                bounded=self._fused_compute)
+            self.prefill_tile_reads += touched
+            self.prefill_chunks += len(order)
 
-        streams = []
-        for slot in order:
-            j = row_of[slot]
-            ps = self._prefilling[slot]
-            req = self.slot_req[slot]
-            t0, n = int(offs[j]), int(clen[j])
-            ps.stage_k[:, :live] = ck[:, j, :live]
-            ps.stage_v[:, :live] = cv[:, j, :live]
-            streams.append({"seq": req.rid,
-                            "vectors": self._kv_words(ck, cv, j, t0, t0 + n)})
-            ps.consumed = t0 + n
-            self.slot_len[slot] += n          # committed later this same cycle
-            self.prefill_tokens += n
-            if ps.consumed == len(req.prompt):
-                # prefill complete: the FIRST generated token comes from the
-                # prefill logits (no re-feed of prompt[-1] through decode)
-                del self._prefilling[slot]
-                if self.prefix_cache:
-                    # registration is deferred past this cycle's pool
-                    # commit — the final chunk's words are not in the pool
-                    # yet, and nothing can match before the next cycle's
-                    # admissions anyway
-                    self._register_pending.append((req.rid,
-                                                   tuple(req.prompt)))
-                req.generated.append(int(np.argmax(lg[j])))
-                if len(req.generated) >= req.max_new:
-                    req.done = True
-                # stamped AFTER this cycle's pool commit (the token isn't
-                # "served" until its KV traversal lands) — see step()
-                self._token_events.append(req)
-            elif self.prefix_cache:
-                # register the full pages committed so far: a sharer that
-                # arrives mid-prefill can attach the in-progress prefix
-                # instead of waiting for completion. Only whole pages — a
-                # partial-tail entry would end the chain and permanently
-                # shadow the full-page entry (first registration wins),
-                # so the sub-page tail is left for the completion call.
-                pt = self.pool.page_tokens
-                full = ps.consumed - ps.consumed % pt
-                if full >= pt:
-                    self._register_pending.append(
-                        (req.rid, tuple(req.prompt[:full])))
-        return streams
+            streams = []
+            for slot in order:
+                j = row_of[slot]
+                ps = self._prefilling[slot]
+                req = self.slot_req[slot]
+                t0, n = int(offs[j]), int(clen[j])
+                ps.stage_k[:, :live] = ck[:, j, :live]
+                ps.stage_v[:, :live] = cv[:, j, :live]
+                streams.append({"seq": req.rid,
+                                "vectors": self._kv_words(ck, cv, j, t0, t0 + n)})
+                ps.consumed = t0 + n
+                self.slot_len[slot] += n          # committed later this same cycle
+                self.prefill_tokens += n
+                if ps.consumed == len(req.prompt):
+                    # prefill complete: the FIRST generated token comes from the
+                    # prefill logits (no re-feed of prompt[-1] through decode)
+                    del self._prefilling[slot]
+                    if self.prefix_cache:
+                        # registration is deferred past this cycle's pool
+                        # commit — the final chunk's words are not in the pool
+                        # yet, and nothing can match before the next cycle's
+                        # admissions anyway
+                        self._register_pending.append((req.rid,
+                                                       tuple(req.prompt)))
+                    req.generated.append(int(np.argmax(lg[j])))
+                    if len(req.generated) >= req.max_new:
+                        req.done = True
+                    # stamped AFTER this cycle's pool commit (the token isn't
+                    # "served" until its KV traversal lands) — see step()
+                    self._token_events.append(req)
+                elif self.prefix_cache:
+                    # register the full pages committed so far: a sharer that
+                    # arrives mid-prefill can attach the in-progress prefix
+                    # instead of waiting for completion. Only whole pages — a
+                    # partial-tail entry would end the chain and permanently
+                    # shadow the full-page entry (first registration wins),
+                    # so the sub-page tail is left for the completion call.
+                    pt = self.pool.page_tokens
+                    full = ps.consumed - ps.consumed % pt
+                    if full >= pt:
+                        self._register_pending.append(
+                            (req.rid, tuple(req.prompt[:full])))
+            return streams
 
     def _collect_decode(self):
         """Port C: pending appends (last step's KV words) + attention-read
@@ -1076,77 +1087,90 @@ class MultiPortEngine:
         Returns (R-port tiles touched, ideal per-slot ceil tile bound,
         per-device tile reads, critical-path chain, the in-flight handle)
         — tile accounting is pure host arithmetic over live lengths, so it
-        needs no results."""
-        nl, _, hkv, hd = self._kv_dims
-        if self.n_kv_shards == 1:
-            nb = _bucket(len(self.slot_req), lo=self._init_slots)
-            row_of = {i: i for i in active}
-            groups = [list(active)]
-        else:
-            nb, row_of, groups = self._group_rows(
-                active, base=_bucket(len(self.slot_req),
-                                     lo=self._init_slots))
-        need_of = {i: rows.shape[0] + 1                 # post-append lens
-                   for i, rows in zip(active, gathered)}
-        stage_s = self._stage_len(max(need_of.values(), default=1))
-        stage_k = self._stage_bufs.get(("decode", "k"),
-                                       (nl, nb, stage_s, hkv, hd))
-        stage_v = self._stage_bufs.get(("decode", "v"),
-                                       (nl, nb, stage_s, hkv, hd))
-        lens = np.full((nb,), self._dead_row, np.int32)
-        last_tokens = np.zeros((nb, 1), np.int32)
-        for i, rows in zip(active, gathered):
-            j = row_of[i]
-            t = rows.shape[0]
-            w = np.asarray(rows, np.float32).reshape(t, nl, 2, hkv, hd)
-            stage_k[:, j, :t] = np.moveaxis(w[:, :, 0], 0, 1)
-            stage_v[:, j, :t] = np.moveaxis(w[:, :, 1], 0, 1)
-            lens[j] = t
-            r = self.slot_req[i]
-            seqs = r.generated or r.prompt
-            last_tokens[j, 0] = seqs[-1]
+        needs no results. Traced as the ``engine.decode.stage`` span, with
+        the rows and the bytes staged; each row's read of its gathered
+        words to the host is an ``engine.pool.gather`` span inside it."""
+        with obs.span("engine.decode.stage", rows=len(active)) as counts:
+            nl, _, hkv, hd = self._kv_dims
+            if self.n_kv_shards == 1:
+                nb = _bucket(len(self.slot_req), lo=self._init_slots)
+                row_of = {i: i for i in active}
+                groups = [list(active)]
+            else:
+                nb, row_of, groups = self._group_rows(
+                    active, base=_bucket(len(self.slot_req),
+                                         lo=self._init_slots))
+            need_of = {i: rows.shape[0] + 1             # post-append lens
+                       for i, rows in zip(active, gathered)}
+            stage_s = self._stage_len(max(need_of.values(), default=1))
+            stage_k = self._stage_bufs.get(("decode", "k"),
+                                           (nl, nb, stage_s, hkv, hd))
+            stage_v = self._stage_bufs.get(("decode", "v"),
+                                           (nl, nb, stage_s, hkv, hd))
+            lens = np.full((nb,), self._dead_row, np.int32)
+            last_tokens = np.zeros((nb, 1), np.int32)
+            for i, rows in zip(active, gathered):
+                j = row_of[i]
+                t = rows.shape[0]
+                with obs.span("engine.pool.gather") as c:
+                    w = np.asarray(rows, np.float32).reshape(t, nl, 2, hkv,
+                                                             hd)
+                    c["d2h_bytes"] = rows.nbytes
+                stage_k[:, j, :t] = np.moveaxis(w[:, :, 0], 0, 1)
+                stage_v[:, j, :t] = np.moveaxis(w[:, :, 1], 0, 1)
+                lens[j] = t
+                r = self.slot_req[i]
+                seqs = r.generated or r.prompt
+                last_tokens[j, 0] = seqs[-1]
 
-        state = {"len": jnp.asarray(lens),
-                 "cache_k": jnp.asarray(stage_k),
-                 "cache_v": jnp.asarray(stage_v)}
-        st, logits = self._decode(self.params, state,
-                                  {"inputs": jnp.asarray(last_tokens)})
-        inflight = _InFlight(cycle=self.cycles, vclock_end=self.vclock,
-                             active=list(active), row_of=row_of, lens=lens,
-                             state=st, logits=logits,
-                             rids={i: self.slot_req[i].rid for i in active})
-        bounded = self._fused_compute and self.length_bound
-        tiles, bound, per_dev, crit = self._tiles_touched(
-            [[need_of[i] for i in g] for g in groups], stage_s,
-            bounded=bounded, splits=self.num_kv_splits)
-        return tiles, bound, per_dev, crit, inflight
+            state = {"len": jnp.asarray(lens),
+                     "cache_k": jnp.asarray(stage_k),
+                     "cache_v": jnp.asarray(stage_v)}
+            st, logits = self._decode(self.params, state,
+                                      {"inputs": jnp.asarray(last_tokens)})
+            counts["h2d_bytes"] = (lens.nbytes + stage_k.nbytes
+                                   + stage_v.nbytes + last_tokens.nbytes)
+            inflight = _InFlight(cycle=self.cycles, vclock_end=self.vclock,
+                                 active=list(active), row_of=row_of, lens=lens,
+                                 state=st, logits=logits,
+                                 rids={i: self.slot_req[i].rid
+                                       for i in active})
+            bounded = self._fused_compute and self.length_bound
+            tiles, bound, per_dev, crit = self._tiles_touched(
+                [[need_of[i] for i in g] for g in groups], stage_s,
+                bounded=bounded, splits=self.num_kv_splits)
+            return tiles, bound, per_dev, crit, inflight
 
     def _retire(self, inf: _InFlight) -> None:
         """Force an in-flight decode cycle's device results and fold them
         into host state: each slot's new KV word becomes the NEXT cycle's
         append, its token lands on the request, and finished requests get
         their latency stamps — at the virtual-clock time their cycle's
-        traversals committed, not the later wall moment retirement ran."""
-        ck = np.asarray(inf.state["cache_k"])
-        cv = np.asarray(inf.state["cache_v"])
-        nxt = np.asarray(jnp.argmax(inf.logits, axis=-1))
-        now_wall = time.perf_counter()
-        for i in inf.active:
-            j = inf.row_of[i]
-            r = self.slot_req[i]
-            if r is None or r.rid != inf.rids.get(i):
-                # the slot was evicted (e.g. a chaos cancel) and possibly
-                # reassigned while this dispatch was outstanding — folding
-                # the stale row back in would corrupt the new occupant
-                continue
-            self._pending[i] = self._kv_words(ck, cv, j, int(inf.lens[j]),
-                                              int(inf.lens[j]) + 1)[0]
-            r.generated.append(int(nxt[j]))
-            if len(r.generated) >= r.max_new:
-                r.done = True
-                r.finish_cycle = inf.cycle
-                r.finish_tick = inf.vclock_end
-                r.t_finish = now_wall
+        traversals committed, not the later wall moment retirement ran.
+        Traced as the ``engine.retire`` span, with the bytes read back."""
+        with obs.span("engine.retire") as counts:
+            ck = np.asarray(inf.state["cache_k"])
+            cv = np.asarray(inf.state["cache_v"])
+            nxt = np.asarray(jnp.argmax(inf.logits, axis=-1))
+            counts["d2h_bytes"] = ck.nbytes + cv.nbytes + nxt.nbytes
+            now_wall = time.perf_counter()
+            for i in inf.active:
+                j = inf.row_of[i]
+                r = self.slot_req[i]
+                if r is None or r.rid != inf.rids.get(i):
+                    # the slot was evicted (e.g. a chaos cancel) and
+                    # possibly reassigned while this dispatch was
+                    # outstanding — folding the stale row back in would
+                    # corrupt the new occupant
+                    continue
+                t = int(inf.lens[j])
+                self._pending[i] = self._kv_words(ck, cv, j, t, t + 1)[0]
+                r.generated.append(int(nxt[j]))
+                if len(r.generated) >= r.max_new:
+                    r.done = True
+                    r.finish_cycle = inf.cycle
+                    r.finish_tick = inf.vclock_end
+                    r.t_finish = now_wall
 
     def _service_status(self) -> dict:
         return {"cycle": self.cycles,
@@ -1234,131 +1258,129 @@ class MultiPortEngine:
         cycle's decode compute without forcing it — the device executes it
         while the host plans the next macro-cycle. State evolution is
         bit-identical to the synchronous loop; only the forcing point
-        moved."""
-        # chaos delayed retirement: while stalled the in-flight decode is
-        # NOT forced this cycle (and no new decode work is collected or
-        # dispatched below) — evict/admit/prefill keep running
-        stalled = self.retire_stall_cycles > 0
-        if stalled:
-            self.retire_stall_cycles -= 1
-            if self._inflight is not None:
-                self.stalled_retirements += 1
-        else:
-            self.flush()
-        # deadline shedding happens at the HEAD of the cycle, before any
-        # admission decision: expired heads never reach a slot, a page, or
-        # a pool traversal (head-only — see AdmissionQueue)
-        for req in self.admission.shed_expired_heads(self.vclock):
-            self._shed(req, "deadline")
-        if self.overload is not None:
-            self.overload.observe(self.admission.ready_depth(self.vclock),
-                                  cycle=self.cycles, tick=self.vclock)
-        self._freed_slots_this_cycle = set()
-        self._token_events = []
-        cfg = self._port_enables()
-        sched = build_schedule(cfg)
-        slots = sched.slots
-        if self.single_port:
-            # bare macro: one port per CLK (rotate through enabled ports)
-            slots = fsm.rotate_single_port(slots, self._sp_rotate)
-            self._sp_rotate += 1
-
-        collected = {"status": {}, "scrub": [], "admits": [],
-                     "appends": [], "active": [], "reads": []}
-
-        def service(state, port):
-            if port == EVICT:
-                state["scrub"] = self._collect_evict()
-            elif port == PREFILL:
-                state["admits"] = self._collect_prefill()
-            elif port == DECODE:
-                if not stalled:
-                    (state["appends"], state["active"],
-                     state["reads"]) = self._collect_decode()
+        moved. Traced as the ``engine.step`` span (``repro.obs``)."""
+        with obs.span("engine.step", cycle=self.cycles):
+            # chaos delayed retirement: while stalled the in-flight decode is
+            # NOT forced this cycle (and no new decode work is collected or
+            # dispatched below) — evict/admit/prefill keep running
+            stalled = self.retire_stall_cycles > 0
+            if stalled:
+                self.retire_stall_cycles -= 1
+                if self._inflight is not None:
+                    self.stalled_retirements += 1
             else:
-                state["status"] = self._service_status()
-            return state
+                self.flush()
+            # deadline shedding happens at the HEAD of the cycle, before any
+            # admission decision: expired heads never reach a slot, a page, or
+            # a pool traversal (head-only — see AdmissionQueue)
+            for req in self.admission.shed_expired_heads(self.vclock):
+                self._shed(req, "deadline")
+            if self.overload is not None:
+                self.overload.observe(self.admission.ready_depth(self.vclock),
+                                      cycle=self.cycles, tick=self.vclock)
+            self._freed_slots_this_cycle = set()
+            self._token_events = []
+            cfg = self._port_enables()
+            sched = build_schedule(cfg)
+            slots = sched.slots
+            if self.single_port:
+                # bare macro: one port per CLK (rotate through enabled ports)
+                slots = fsm.rotate_single_port(slots, self._sp_rotate)
+                self._sp_rotate += 1
 
-        walk_cfg = PortConfig(
-            enabled=tuple(p in slots for p in range(4)),
-            roles=cfg.roles, priority=cfg.priority)
-        collected = fsm.walk_static(walk_cfg, collected, service)
-        status = collected["status"]
-        scrub, admits = collected["scrub"], collected["admits"]
-        appends, active, reads = (collected["appends"], collected["active"],
-                                  collected["reads"])
+            collected = {"status": {}, "scrub": [], "admits": [],
+                         "appends": [], "active": [], "reads": []}
 
-        # schedule the cycle's traffic: hazard analysis over page
-        # footprints picks the per-traversal port mix, then the plan
-        # commits against the physical pool in program order
-        t0 = self.pool.traversals
-        phases = self._build_phases(scrub, admits, appends, reads)
-        plan = sched_mod.plan(phases, mode=self.schedule_mode,
-                              max_ports=self.max_ports,
-                              split_roles=self._split_roles)
-        gathered = self._commit(plan)
-        self.schedule_log.append(
-            tuple(t.phase_ids() for t in plan.traversals))
-        if len({ph.phase for ph in phases}) > 1:
-            self.multi_phase_cycles += 1
-            if plan.co_scheduled:
-                self.coscheduled_cycles += 1
-        for s in appends:                          # appends are now committed
-            slot = next(i for i in range(len(self.slot_req))
-                        if self.slot_req[i] is not None
-                        and self.slot_req[i].rid == s["seq"])
-            self.slot_len[slot] += 1
-            self._pending.pop(slot, None)
-        # completed prompts' pages join the prefix index now that their
-        # final chunk's words are committed (see _collect_prefill)
-        for rid, ptoks in self._register_pending:
-            if rid in self.pool.tables:
-                self.pool.register_prefix(rid, ptoks)
-        self._register_pending = []
+            def service(state, port):
+                if port == EVICT:
+                    state["scrub"] = self._collect_evict()
+                elif port == PREFILL:
+                    state["admits"] = self._collect_prefill()
+                elif port == DECODE:
+                    if not stalled:
+                        (state["appends"], state["active"],
+                         state["reads"]) = self._collect_decode()
+                else:
+                    state["status"] = self._service_status()
+                return state
 
-        dt = self.pool.traversals - t0
-        if dt == 0:
-            # an idle (status-only) macro-cycle still costs one virtual
-            # tick — otherwise the clock would stall while the open-loop
-            # engine waits on future arrivals
-            self.idle_ticks += 1
-        # latency stamps for this cycle's prefill-produced tokens: a first
-        # token counts as served once its cycle's traversals COMMITTED, at
-        # the post-commit virtual-clock reading
-        now_tick, now_wall = self.vclock, time.perf_counter()
-        for r in self._token_events:
-            r.first_token_cycle = self.cycles
-            r.first_token_tick = now_tick
-            r.t_first = now_wall
-            if r.done:
-                r.finish_cycle = self.cycles
-                r.finish_tick = now_tick
-                r.t_finish = now_wall
-        if admits:
-            self.prefill_steps += 1
-            self.prefill_traversals += dt
-        if active:
-            self.decode_steps += 1
-            self.decode_traversals += dt
-            tiles, bound, per_dev, crit, inflight = self._dispatch_decode(
-                active, gathered)
-            self._inflight = inflight
-            self.decode_tile_reads += tiles
-            self.decode_critical_tiles += crit
-            for d, t in enumerate(per_dev):
-                self.decode_tile_reads_by_dev[d] += t
-            if appends:
-                self.steady_decode_steps += 1
-                self.steady_decode_traversals += dt
-                self.steady_decode_tile_reads += tiles
-                self.steady_decode_tile_bound += bound
-                self.steady_decode_critical_tiles += crit
-                for d, t in enumerate(per_dev):
-                    self.steady_decode_tile_reads_by_dev[d] += t
+            walk_cfg = PortConfig(
+                enabled=tuple(p in slots for p in range(4)),
+                roles=cfg.roles, priority=cfg.priority)
+            collected = fsm.walk_static(walk_cfg, collected, service)
+            status = collected["status"]
+            scrub, admits = collected["scrub"], collected["admits"]
+            appends, active, reads = (collected["appends"], collected["active"],
+                                      collected["reads"])
 
-        self.cycles += 1
-        self.port_log.append(slots)
-        return status
+            # schedule the cycle's traffic: hazard analysis over page
+            # footprints picks the per-traversal port mix, then the plan
+            # commits against the physical pool in program order
+            t0 = self.pool.traversals
+            phases = self._build_phases(scrub, admits, appends, reads)
+            plan = sched_mod.plan(phases, mode=self.schedule_mode,
+                                  max_ports=self.max_ports,
+                                  split_roles=self._split_roles)
+            gathered = self._commit(plan)
+            self.schedule_log.append(
+                tuple(t.phase_ids() for t in plan.traversals))
+            if len({ph.phase for ph in phases}) > 1:
+                self.multi_phase_cycles += 1
+                if plan.co_scheduled:
+                    self.coscheduled_cycles += 1
+            for s in appends:                          # appends are now committed
+                slot = next(i for i in range(len(self.slot_req))
+                            if self.slot_req[i] is not None
+                            and self.slot_req[i].rid == s["seq"])
+                self.slot_len[slot] += 1
+                self._pending.pop(slot, None)
+            # completed prompts' pages join the prefix index now that their
+            # final chunk's words are committed (see _collect_prefill)
+            for rid, ptoks in self._register_pending:
+                if rid in self.pool.tables:
+                    self.pool.register_prefix(rid, ptoks)
+            self._register_pending = []
+
+            dt = self.pool.traversals - t0
+            if dt == 0:
+                # an idle (status-only) macro-cycle still costs one virtual
+                # tick — otherwise the clock would stall while the open-loop
+                # engine waits on future arrivals
+                self.idle_ticks += 1
+            # latency stamps for this cycle's prefill-produced tokens: a first
+            # token counts as served once its cycle's traversals COMMITTED, at
+            # the post-commit virtual-clock reading
+            now_tick, now_wall = self.vclock, time.perf_counter()
+            for r in self._token_events:
+                r.first_token_cycle = self.cycles
+                r.first_token_tick = now_tick
+                r.t_first = now_wall
+                if r.done:
+                    r.finish_cycle = self.cycles
+                    r.finish_tick = now_tick
+                    r.t_finish = now_wall
+            if admits:
+                self.prefill_steps += 1
+                self.prefill_traversals += dt
+            if active:
+                self.decode_steps += 1
+                self.decode_traversals += dt
+                tiles, bound, per_dev, crit, inflight = self._dispatch_decode(
+                    active, gathered)
+                self._inflight = inflight
+                self.decode_tile_reads += tiles
+                if appends:
+                    self.steady_decode_steps += 1
+                    self.steady_decode_traversals += dt
+                    self.steady_decode_tile_reads += tiles
+                    self.steady_decode_tile_bound += bound
+                    self.steady_decode_critical_tiles += crit
+                    for d, t in enumerate(per_dev):
+                        self.steady_decode_tile_reads_by_dev[d] += t
+
+            self.cycles += 1
+            self.port_log.append(slots)
+            return status
 
     def run(self, max_cycles: int = 10_000) -> list[Request]:
         while self.pending_work() and self.cycles < max_cycles:
