@@ -233,19 +233,20 @@ class _PrefillState:
 
 @dataclasses.dataclass
 class _InFlight:
-    """One dispatched-but-unretired decode macro-cycle: the jitted step's
-    un-forced device results plus the host metadata needed to retire them.
-    Created at the end of ``step()`` (JAX async dispatch — the jit call
-    returned futures), consumed at the START of the next ``step()`` (or by
-    ``flush()``), so the device executes cycle N while the host plans
-    cycle N+1."""
+    """One dispatched-but-unretired decode macro-cycle: the decode
+    program's un-forced device results — each staged row's appended KV
+    word and its greedy next token, not the staged caches or the logits —
+    plus the host metadata needed to retire them. Created at the end of
+    ``step()`` (JAX async dispatch — the jit call returned futures),
+    consumed at the START of the next ``step()`` (or by ``flush()``), so
+    the device executes cycle N while the host plans cycle N+1."""
     cycle: int                     # macro-cycle index the work belongs to
     vclock_end: int                # virtual clock after that cycle's commit
     active: list                   # slots the decode step served
     row_of: dict                   # slot -> staged batch row
-    lens: np.ndarray               # per-row pre-append cache lengths
-    state: dict                    # un-forced jit outputs (cache_k/cache_v)
-    logits: object                 # un-forced next-token logits
+    words: object                  # un-forced [nb, L, 2, Hkv, D] f32: each
+                                   # row's KV word at its pre-append length
+    tokens: object                 # un-forced [nb] int32 argmax next tokens
     rids: dict = dataclasses.field(default_factory=dict)
                                    # slot -> rid at dispatch time: retirement
                                    # skips rows whose slot was reassigned
@@ -492,12 +493,24 @@ class MultiPortEngine:
         kmesh = mesh if self.n_kv_shards > 1 else None
         nsp = self.num_kv_splits
         def decode_program(p, s, b):
+            """The decode step, returning only what ``_retire`` reads: each
+            row's appended KV word in pool word order (``_kv_words``'s
+            layout) and its greedy next token. Dead and padded rows carry
+            a negative or zero length; their index is clamped to 0 and the
+            host never reads them."""
             with jax.named_scope("engine.decode"):
-                return decode_step(p, cfg, s, b, kernel_mode=attn_mode,
-                                   seq_tile=tile, length_mask=length_bound,
-                                   dynamic_grid=dyn, num_kv_splits=nsp,
-                                   interpret=interpret, mesh=kmesh,
-                                   mesh_axis=kv_axis, port_mix=pmix)
+                st, logits = decode_step(
+                    p, cfg, s, b, kernel_mode=attn_mode, seq_tile=tile,
+                    length_mask=length_bound, dynamic_grid=dyn,
+                    num_kv_splits=nsp, interpret=interpret, mesh=kmesh,
+                    mesh_axis=kv_axis, port_mix=pmix)
+                rows = jnp.arange(s["len"].shape[0])
+                at = jnp.maximum(s["len"], 0)
+                k = st["cache_k"][:, rows, at]              # [L, nb, hkv, hd]
+                v = st["cache_v"][:, rows, at]
+                words = jnp.moveaxis(jnp.stack([k, v], axis=1), 2, 0)
+                return (words,                             # [nb, L, 2, ...]
+                        jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
         def prefill_program(p, s, b):
             with jax.named_scope("engine.prefill"):
@@ -1126,13 +1139,13 @@ class MultiPortEngine:
             state = {"len": jnp.asarray(lens),
                      "cache_k": jnp.asarray(stage_k),
                      "cache_v": jnp.asarray(stage_v)}
-            st, logits = self._decode(self.params, state,
-                                      {"inputs": jnp.asarray(last_tokens)})
+            words, tokens = self._decode(self.params, state,
+                                         {"inputs": jnp.asarray(last_tokens)})
             counts["h2d_bytes"] = (lens.nbytes + stage_k.nbytes
                                    + stage_v.nbytes + last_tokens.nbytes)
             inflight = _InFlight(cycle=self.cycles, vclock_end=self.vclock,
-                                 active=list(active), row_of=row_of, lens=lens,
-                                 state=st, logits=logits,
+                                 active=list(active), row_of=row_of,
+                                 words=words, tokens=tokens,
                                  rids={i: self.slot_req[i].rid
                                        for i in active})
             bounded = self._fused_compute and self.length_bound
@@ -1147,12 +1160,12 @@ class MultiPortEngine:
         append, its token lands on the request, and finished requests get
         their latency stamps — at the virtual-clock time their cycle's
         traversals committed, not the later wall moment retirement ran.
-        Traced as the ``engine.retire`` span, with the bytes read back."""
+        Traced as the ``engine.retire`` span, with the bytes read back: one
+        KV word and one token per staged row."""
         with obs.span("engine.retire") as counts:
-            ck = np.asarray(inf.state["cache_k"])
-            cv = np.asarray(inf.state["cache_v"])
-            nxt = np.asarray(jnp.argmax(inf.logits, axis=-1))
-            counts["d2h_bytes"] = ck.nbytes + cv.nbytes + nxt.nbytes
+            words = np.asarray(inf.words)
+            nxt = np.asarray(inf.tokens)
+            counts["d2h_bytes"] = words.nbytes + nxt.nbytes
             now_wall = time.perf_counter()
             for i in inf.active:
                 j = inf.row_of[i]
@@ -1163,8 +1176,7 @@ class MultiPortEngine:
                     # outstanding — folding the stale row back in would
                     # corrupt the new occupant
                     continue
-                t = int(inf.lens[j])
-                self._pending[i] = self._kv_words(ck, cv, j, t, t + 1)[0]
+                self._pending[i] = words[j].reshape(-1)
                 r.generated.append(int(nxt[j]))
                 if len(r.generated) >= r.max_new:
                     r.done = True

@@ -122,9 +122,12 @@ def test_traced_steps_nest_and_count(setup, tmp_path):
     for s in stages:
         assert s.counts["h2d_bytes"] == cache + 2 * nb * 4
         assert 1 <= s.counts["rows"] <= nb
-    # retire reads both returned caches back, and one token per row
+    # retire reads back one KV word per row, a word being
+    # 2 * n_layers * n_kv_heads * head_dim_ f32 values, and one int32
+    # token per row
+    word_bytes = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim_ * 4
     for s in (s for s in spans if s.name == "engine.retire"):
-        assert s.counts["d2h_bytes"] == cache + nb * 4
+        assert s.counts["d2h_bytes"] == nb * word_bytes + nb * 4
     for s in (s for s in spans if s.name == "engine.pool.issue"):
         assert s.counts["lanes"] > 0 and s.counts["h2d_bytes"] > 0
 
