@@ -1,8 +1,16 @@
-"""The plain reference: a Qwen2 decoder in ``jax.numpy``, float32 at
-HIGHEST matmul precision, layer by layer, and its lower-precision control.
+"""Qwen2 (``model_type`` ``qwen2``): the program's registry entry it
+needs, its per-token matmul parameters, and the plain reference — a Qwen2
+decoder in ``jax.numpy``, float32 at HIGHEST matmul precision, layer by
+layer — with its lower-precision control.
 
-It imports nothing of the program. It reads the benchmark's own weights
-(``weights.py``) by the names of the program's parameter tree:
+``program(conf)`` gives the registry ``ArchConfig`` attributes the
+configuration file fixes; the file must state ``hidden_size ==
+num_attention_heads * head_dim``, as every Qwen2 does. ``matmul_params(conf)``
+counts the non-embedding matmul parameters one token passes through: the
+attention projections and the SwiGLU MLP of every layer.
+
+The reference imports nothing of the program. It reads the benchmark's
+own weights (``weights.py``) by the names of the program's parameter tree:
 ``embed.w``, per layer ``ln1.scale``, ``attn.{wq,wk,wv}.{w,b}``,
 ``attn.wo.w``, ``ln2.scale``, ``ffn.{w_gate,w_up,w_down}.w``, then
 ``final_norm.scale`` and ``lm_head.w``, or, where the configuration ties
@@ -18,7 +26,9 @@ position. With ``control=True`` it also runs the control — the same model
 computed in int8 (weights per output channel, activations per token,
 symmetric) — and returns the gap of the token the control puts first, at
 the same positions. A correct greedy program reads near-tie gaps only;
-the control shows what the next precision down would read.
+the control shows what the next precision down would read. A decoder
+that differs only in its layer passes its own jitted ``layer`` (same
+arguments as ``_layer``) to ``hidden`` and ``check``.
 """
 from __future__ import annotations
 
@@ -32,6 +42,35 @@ F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 ROWS = 8            # sequences per reference batch
 HEAD_ROWS = 256     # positions per lm_head block
+
+
+def program(conf: dict) -> dict:
+    """The registry ``ArchConfig`` attributes the file fixes, with their
+    values. Raises ValueError where the file is no Qwen2."""
+    hd = conf["num_attention_heads"] * conf["head_dim"]
+    if conf["hidden_size"] != hd:
+        raise ValueError(f"hidden_size {conf['hidden_size']} is not "
+                         f"num_attention_heads * head_dim = {hd}")
+    return {"n_layers": conf["num_hidden_layers"],
+            "d_model": conf["hidden_size"],
+            "n_heads": conf["num_attention_heads"],
+            "n_kv_heads": conf["num_key_value_heads"],
+            "head_dim_": conf["head_dim"],
+            "d_ff": conf["intermediate_size"],
+            "vocab": conf["vocab_size"],
+            "rope_theta": float(conf["rope_theta"]),
+            "norm_eps": float(conf["rms_norm_eps"]), "qkv_bias": True}
+
+
+def matmul_params(conf: dict) -> int:
+    """Non-embedding matmul parameters one token passes through: Q, K, V
+    and O, and the three SwiGLU matrices, in every layer."""
+    d = conf["hidden_size"]
+    hd = conf["num_attention_heads"] * conf["head_dim"]
+    kv = conf["num_key_value_heads"] * conf["head_dim"]
+    per_layer = d * hd + 2 * d * kv + hd * d \
+        + 3 * d * conf["intermediate_size"]
+    return per_layer * conf["num_hidden_layers"]
 
 
 def _int8(x, axis):
@@ -92,12 +131,13 @@ def _layer(x, layers, i, *, w, int8: bool):
     return x + _mm(jax.nn.silu(gate) * up, f["w_down"]["w"], int8)
 
 
-def hidden(params, w, ids: np.ndarray, *, int8: bool) -> jax.Array:
+def hidden(params, w, ids: np.ndarray, *, int8: bool, layer=_layer
+           ) -> jax.Array:
     """Final-layer hidden states ``[R, S, hidden]`` for token ids
     ``[R, S]`` (rows padded at the end; attention is causal)."""
     x = jnp.take(params["embed"]["w"], jnp.asarray(ids), axis=0).astype(F32)
     for i in range(w.layers):
-        x = _layer(x, params["layers"], i, w=w, int8=int8)
+        x = layer(x, params["layers"], i, w=w, int8=int8)
     return x
 
 
@@ -115,8 +155,8 @@ def _gaps(h, hc, norm, lm_head, served, *, w, control: bool):
     return gap, best - jnp.take_along_axis(z, pick[:, None], -1)[:, 0]
 
 
-def check(params, w, seqs: list, max_len: int, *, control: bool = False
-          ) -> dict:
+def check(params, w, seqs: list, max_len: int, *, control: bool = False,
+          layer=_layer) -> dict:
     """``seqs``: ``[(prompt, served)]`` token lists. Returns ``{"gaps":
     [...]}`` (one per served token, in order) and, with ``control``,
     ``"control_gaps"``. Every program it runs has one shape: batches of
@@ -137,8 +177,10 @@ def check(params, w, seqs: list, max_len: int, *, control: bool = False
                 served[j, n - 1:n - 1 + len(out)] = out
                 want[j, n - 1:n - 1 + len(out)] = True
             d = w.hidden
-            h = hidden(params, w, ids, int8=False).reshape(-1, d)
-            hc = (hidden(params, w, ids, int8=True).reshape(-1, d)
+            h = hidden(params, w, ids, int8=False,
+                       layer=layer).reshape(-1, d)
+            hc = (hidden(params, w, ids, int8=True,
+                         layer=layer).reshape(-1, d)
                   if control else h)
             srv = jnp.asarray(served.reshape(-1))
             g, c = [], []

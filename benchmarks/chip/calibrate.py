@@ -8,15 +8,16 @@ One process, so that every run after the first finds its programs
 compiled. For each seed (and, with ``--rates``, each offered rate in
 requests per second, overriding the traffic file's; given as many seeds
 as rates, each rate runs on its own seed) it makes one run of the cell as
-``run.py`` does, then runs the plain reference and its int8
-control over the same served tokens. Each run prints one JSON line: the
-rate, the seed, the cell's end-to-end metrics, ``correct`` with the
-numbers compared, the program's logit gaps (mean, widest, ...: the lower
-readings of the limits), the control's (the upper readings) and whether
-the control passes the cell's limits by the same comparison (it must
-not: the script stops there if it does), and the admission queue's depth
-over the window, whose growth marks a rate above the knee. The
-benchmark's own runs never run this.
+``run.py`` does, then runs the plain reference of the configuration's
+architecture (``arch/<model_type>.py``) and its int8 control over the
+same served tokens. Each run prints one JSON line: the rate, the seed,
+the cell's end-to-end metrics, ``correct`` with the numbers compared,
+the program's logit gaps (mean, widest, ...: the lower readings of the
+limits), the control's (the upper readings) and whether the control
+passes the cell's limits by the same comparison (it must not: the script
+stops there if it does), and the admission queue's depth over the
+window, whose growth marks a rate above the knee. The benchmark's own
+runs never run this.
 """
 from __future__ import annotations
 

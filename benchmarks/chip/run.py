@@ -22,10 +22,11 @@ reported; otherwise the end-to-end ones. Cells whose metrics need every
 request of the window to have its first token keep serving, without new
 arrivals, for up to ``DRAIN_S`` after the window closes.
 
-After the window the engine is freed and the plain reference
-(``reference.py``) runs over a sample of the finished requests, drawn
-from the seed with the one that served most tokens in it. The run is
-``correct`` when the sampled tokens' logit gaps keep to the cell's limits
+After the window the engine is freed and the plain reference of the
+configuration's architecture (``arch/<model_type>.py``, its ``check``)
+runs over a sample of the finished requests, drawn from the seed with
+the one that served most tokens in it. The run is ``correct`` when the
+sampled tokens' logit gaps keep to the cell's limits
 (``checks/<cell>.json``), every finished request served exactly the
 tokens it asked for, and no program was compiled inside the window (a
 window that compiles measures the compiler).
@@ -69,20 +70,23 @@ def fail(msg: str) -> None:
     raise SystemExit(2)
 
 
-def program_config(arch: str, w, dtype: str):
-    """The program's registry entry for ``arch``, with its ``norm_eps``
-    (an option the entry leaves at its default) set from the file. It
-    must then be the configuration the file states; a run of anything
-    else measures another model."""
+def program_config(cell):
+    """The program's registry entry for the configuration's ``arch``,
+    with its ``norm_eps`` (an option the entry leaves at its default) set
+    from the file. It must then be the configuration the file states, as
+    the architecture's module reads it (``program``), in the file's
+    dtype; a run of anything else measures another model."""
     import dataclasses
 
     from repro.configs import registry
-    cfg = dataclasses.replace(registry.get(arch), norm_eps=w.norm_eps)
-    want = {"n_layers": w.layers, "d_model": w.hidden, "n_heads": w.heads,
-            "n_kv_heads": w.kv_heads, "head_dim_": w.head_dim,
-            "d_ff": w.ffn, "vocab": w.vocab, "rope_theta": w.rope_theta,
-            "norm_eps": w.norm_eps, "qkv_bias": True,
-            "param_dtype": dtype, "compute_dtype": dtype}
+    conf, dtype = cell.config, cell.config["torch_dtype"]
+    try:
+        want = dict(cell.arch.program(conf), param_dtype=dtype,
+                    compute_dtype=dtype)
+    except ValueError as e:
+        fail(f"configuration file of {conf['arch']}: {e}")
+    cfg = dataclasses.replace(registry.get(conf["arch"]),
+                              norm_eps=cell.widths.norm_eps)
     got = {k: getattr(cfg, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
@@ -181,8 +185,7 @@ def measure(cell, *, seed: int, seconds: float, trace: bool, devs: list,
     window."""
     import jax
 
-    from benchmarks.chip import discover, driver, peaks, record, reference
-    from benchmarks.chip import stats
+    from benchmarks.chip import discover, driver, peaks, record, stats
     from benchmarks.chip import trace_reduce, warm, weights
     from repro.models import init_params
     from repro.serve.engine import MultiPortEngine
@@ -190,7 +193,7 @@ def measure(cell, *, seed: int, seconds: float, trace: bool, devs: list,
     meter = driver.CompileMeter()
     conf, w, traffic = cell.config, cell.widths, cell.traffic
     pk = peaks.peaks(devs[0].device_kind)
-    cfg = program_config(conf["arch"], w, conf["torch_dtype"])
+    cfg = program_config(cell)
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     params = weights.make_weights(shapes, seed, tied=w.tied)
     ec = conf["engine"]
@@ -320,7 +323,7 @@ def measure(cell, *, seed: int, seconds: float, trace: bool, devs: list,
     gc.collect()
     note("window closed; the reference")
     t_ref = time.perf_counter()
-    ref = reference.check(params, w, seqs, ec["max_len"],
+    ref = cell.arch.check(params, w, seqs, ec["max_len"],
                           control=calibrate) if seqs else {"gaps": []}
     gaps = ref["gaps"]
     got = gap_numbers(gaps)

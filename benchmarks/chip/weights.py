@@ -1,10 +1,10 @@
 """Random weights from the seed, made by the benchmark on the device.
 
 The benchmark, not the program, makes the weights, so that the plain
-reference (``reference.py``) takes nothing the program made. They are laid
-out as the program's parameter tree (taken from ``jax.eval_shape`` of its
-``init_params``, which allocates nothing) and made in one jitted call, in
-the type each leaf is served in.
+reference (``arch/<model_type>.py``) takes nothing the program made.
+They are laid out as the program's parameter tree (taken from
+``jax.eval_shape`` of its ``init_params``, which allocates nothing) and
+made in one jitted call, in the type each leaf is served in.
 
 Each leaf is drawn by its role, read from its name in the tree:
 
@@ -17,8 +17,9 @@ Each leaf is drawn by its role, read from its name in the tree:
 - a bias: ``0.1 * normal``, so the QKV bias path is exercised;
 - any other matrix ``[..., fan_in, fan_out]``: ``normal / sqrt(fan_in)``.
 
-A program change that renames these leaves makes ``reference.py``'s
-reader fail loudly; it does not change the weights silently.
+A program change that renames these leaves makes the reference's reader
+(``arch/<model_type>.py``) fail loudly; it does not change the weights
+silently.
 """
 from __future__ import annotations
 

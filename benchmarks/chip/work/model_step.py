@@ -1,8 +1,9 @@
 """The whole model's work for the tokens a macro-cycle processed, for
-``mfu``: two FLOPs per parameter of the non-embedding weights for every
-token computed (prompt tokens after prefix hits, and decoded tokens),
-two per ``lm_head`` parameter for every token whose logits are needed
-(each output token), and the attention of ``decode_attn`` and
+``mfu``: two FLOPs per matmul parameter one token passes through
+(``w.matmul_params``, counted by the architecture's module) for every
+token computed (prompt tokens after prefix hits, and decoded tokens), two
+per ``lm_head`` parameter for every token whose logits are needed (each
+output token), and the attention of ``decode_attn`` and
 ``prefill_chunk``. Embedding lookups and norms are not counted.
 """
 from __future__ import annotations
@@ -12,17 +13,9 @@ from benchmarks.chip.work import decode_attn, prefill_chunk
 MATCH = ()
 
 
-def dense_params(w) -> int:
-    """Non-embedding matmul parameters of all layers."""
-    hd, kv = w.heads * w.head_dim, w.kv_heads * w.head_dim
-    per_layer = w.hidden * hd + 2 * w.hidden * kv + hd * w.hidden \
-        + 3 * w.hidden * w.ffn
-    return per_layer * w.layers
-
-
 def count(w, step) -> tuple[float, float]:
     tokens = sum(n for _, n in step.chunks) + len(step.decode_rows)
-    flops = (2 * dense_params(w) * tokens
+    flops = (2 * w.matmul_params * tokens
              + 2 * w.hidden * w.vocab * step.out_tokens
              + decode_attn.count(w, step)[0]
              + prefill_chunk.count(w, step)[0])

@@ -2,6 +2,7 @@
 TPU, and with the chip check skipped, at a tiny size, its comparison with
 the plain reference passes a sound run and fails a broken one and the
 reference's int8 control."""
+import itertools
 import json
 import os
 import pathlib
@@ -15,7 +16,7 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmarks.chip import discover, peaks, reference, run  # noqa: E402
+from benchmarks.chip import discover, peaks, run  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = BENCH["workloads"][0]["name"]
@@ -23,7 +24,8 @@ RUN = ["--workload", CELL, "--seed", str(2**33 + 1), "--seconds", "2",
        "--trace", "0"]
 
 # the reduced qwen2.5-3b preset, served as the benchmark serves a cell
-TINY = {"arch": "qwen2.5-3b", "num_hidden_layers": 2, "hidden_size": 64,
+TINY = {"arch": "qwen2.5-3b", "model_type": "qwen2",
+        "num_hidden_layers": 2, "hidden_size": 64,
         "intermediate_size": 128, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
         "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -68,9 +70,9 @@ def test_run_fails_in_a_directory_of_the_benchmark_alone(tmp_path):
     assert '"correct"' not in got.stdout
 
 
-@pytest.fixture
-def tiny(monkeypatch):
-    """A run of the tiny cell with the chip check skipped."""
+def off_chip(monkeypatch):
+    """Let ``run.measure`` serve on the CPU: every registry entry at its
+    reduced preset, a peaks row for the CPU, a short warm-up."""
     from repro.configs import registry
     monkeypatch.setattr(run, "WARMUP_S", 3.0)
     get = registry.get
@@ -78,13 +80,19 @@ def tiny(monkeypatch):
                         lambda arch, reduced=False: get(arch, reduced=True))
     monkeypatch.setitem(peaks.PEAKS, jax.devices()[0].device_kind,
                         {"flops": 1e12, "hbm_bw": 1e11})
+
+
+@pytest.fixture
+def tiny(monkeypatch):
+    """A run of the tiny cell with the chip check skipped."""
+    off_chip(monkeypatch)
     bench = discover.load_benchmark()
     metrics = [(m, discover.load_module(
         discover.HERE / "metrics" / f"{m['name']}.py"))
         for m in bench["end_to_end"] if discover.reports(m, CELL)]
-    cell = discover.Cell(name="tiny", chips=1, config=TINY, traffic=TRAFFIC,
-                         check={"limits": LIMITS},
-                         metrics=metrics)
+    cell = discover.Cell(name="tiny", chips=1, config=TINY,
+                         arch=discover.arch("qwen2"), traffic=TRAFFIC,
+                         check={"limits": LIMITS}, metrics=metrics)
 
     def go(seed=2**33 + 3):
         return run.measure(cell, seed=seed, seconds=4.0, trace=False,
@@ -106,20 +114,27 @@ def _break_step(monkeypatch, fault):
     """Break the timed path underneath the harness, through the engine's
     public ``step``: ``token`` alters one request's newest token where it
     is produced; ``swap`` exchanges two requests' newest tokens, as a
-    batch whose rows are read back in the wrong order."""
+    batch whose rows are read back in the wrong order. Each step picks
+    the next of the requests that gained a token, so that every slot's
+    requests are hit: a fault kept to the first slot can miss every
+    request that finishes in a window that a loaded host cuts to a few
+    steps."""
     from repro.serve.engine import MultiPortEngine
     step = MultiPortEngine.step
+    steps = itertools.count()
 
     def broken(self):
         seen = {id(r): len(r.generated) for r in self.slot_req if r}
         out = step(self)
         new = [r for r in self.slot_req
                if r and len(r.generated) > seen.get(id(r), 0)]
+        i = next(steps)
         if fault == "token" and new:
-            r = new[0]
+            r = new[i % len(new)]
             r.generated[-1] = (r.generated[-1] + 1) % self.cfg.vocab
         elif fault == "swap" and len(new) >= 2:
-            a, b = new[0].generated, new[1].generated
+            a = new[i % len(new)].generated
+            b = new[(i + 1) % len(new)].generated
             a[-1], b[-1] = b[-1], a[-1]
         return out
     monkeypatch.setattr(MultiPortEngine, "step", broken)
@@ -147,9 +162,10 @@ def test_int8_control_departs_from_a_sound_run():
     from benchmarks.chip import weights
     from repro.configs import registry
     from repro.models import init_params
+    qwen2 = discover.arch("qwen2")
     cfg = registry.get("qwen2.5-3b", reduced=True)
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    w = discover.Widths.of(TINY)
+    w = discover.Widths.of(TINY, qwen2)
     for seed in (1, 2, 3):
         params = weights.make_weights(shapes, seed, tied=w.tied)
         rng = np.random.default_rng(seed)
@@ -157,12 +173,12 @@ def test_int8_control_departs_from_a_sound_run():
         ids[:, :24] = rng.integers(0, 256, (8, 24))
         lm = params["embed"]["w"].T
         for t in range(24, 56):          # the reference's greedy tokens
-            h = reference.hidden(params, w, ids, int8=False)[:, t - 1]
-            z = reference._norm(h, params["final_norm"]["scale"],
-                                w.norm_eps) @ lm
+            h = qwen2.hidden(params, w, ids, int8=False)[:, t - 1]
+            z = qwen2._norm(h, params["final_norm"]["scale"],
+                            w.norm_eps) @ lm
             ids[:, t] = np.asarray(z.argmax(-1))
         seqs = [(row[:24].tolist(), row[24:56].tolist()) for row in ids]
-        got = reference.check(params, w, seqs, 64, control=True)
+        got = qwen2.check(params, w, seqs, 64, control=True)
         assert len(got["gaps"]) == len(got["control_gaps"]) == 256
         sound = run.gap_numbers(got["gaps"])
         control = run.gap_numbers(got["control_gaps"])

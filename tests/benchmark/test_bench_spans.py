@@ -128,7 +128,8 @@ def test_traced_run_on_cpu_reports_all_seven(monkeypatch):
     metrics = [(m, discover.load_module(
         discover.HERE / "metrics" / f"{m['name']}.py"))
         for m in bench["per_layer"] if m["name"] in NEW]
-    cell = discover.Cell(name="tiny", chips=1, config=TINY, traffic=TRAFFIC,
+    cell = discover.Cell(name="tiny", chips=1, config=TINY,
+                         arch=discover.arch("qwen2"), traffic=TRAFFIC,
                          check={"limits": LIMITS}, metrics=metrics)
     obs.clear()
     out = run.measure(cell, seed=2**33 + 5, seconds=3.0, trace=True,

@@ -91,9 +91,12 @@ def test_a_stall_moves_the_tail():
     assert stats.percentile([], 90) is None
 
 
-W = discover.Widths(layers=2, hidden=8, heads=4, kv_heads=2, head_dim=2,
-                    ffn=16, vocab=10, dtype_bytes=2, rope_theta=1e4,
-                    norm_eps=1e-6)
+CONF = {"model_type": "qwen2", "num_hidden_layers": 2, "hidden_size": 8,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 2,
+        "intermediate_size": 16, "vocab_size": 10,
+        "torch_dtype": "bfloat16", "rope_theta": 1e4, "rms_norm_eps": 1e-6}
+QWEN2 = discover.arch("qwen2")
+W = discover.Widths.of(CONF, QWEN2)
 
 
 def test_decode_attn_work_by_hand():
@@ -119,7 +122,7 @@ def test_pool_step_work_by_hand():
 def test_model_step_work_by_hand():
     s = StepWork(0, 1, decode_rows=[3], chunks=[(0, 2)], out_tokens=1)
     dense = 2 * (8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16)   # 2 layers
-    assert model_step.dense_params(W) == dense
+    assert QWEN2.matmul_params(CONF) == W.matmul_params == dense
     flops = (2 * dense * 3 + 2 * 8 * 10 * 1
              + decode_attn.count(W, s)[0] + prefill_chunk.count(W, s)[0])
     assert model_step.count(W, s) == (float(flops), 0.0)
